@@ -6,7 +6,12 @@ Instead each P runs in a subprocess and reports the *per-device* compiled
 cost of one EM iteration (exact loop-aware HLO analysis): FLOPs/device
 must fall as 1/P (the paper's linear-scaling regime) while the reduction
 payload stays constant — the same accounting the §Roofline cells use.
-Wall-clock is reported as a secondary sanity column with this caveat."""
+Wall-clock is reported as a secondary sanity column with this caveat.
+
+A CPU emulation by design: the children run with ``JAX_PLATFORMS=cpu``.
+On a TPU machine the parent (``benchmarks/run.py``) has already touched
+JAX and holds the chip, and a child that reached for it would fail or
+block on the TPU runtime's lock."""
 from __future__ import annotations
 
 import json
@@ -22,7 +27,6 @@ import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count={n_dev}"
 import json, time
 import numpy as np, jax
-from repro import compat
 from repro.core import PEMSVM, SVMConfig, lam_from_C
 from repro.data import make_dna_like
 from repro.launch.hlo_cost import analyze
@@ -32,8 +36,8 @@ X, y = make_dna_like({n}, {k})
 lam = lam_from_C(1e-5) * {n} / 2_500_000
 mesh = None
 if n_dev > 1:
-    mesh = compat.make_mesh((n_dev,), ("data",),
-                         axis_types=("auto",))
+    mesh = jax.make_mesh((n_dev,), ("data",),
+                         axis_types=(jax.sharding.AxisType.Auto,))
 svm = PEMSVM(SVMConfig(lam=lam, max_iters=6, min_iters=6, tol=0.0),
              mesh=mesh)
 data, prior, state = svm._prepare(
@@ -61,6 +65,7 @@ def run(n: int = 40_000, k: int = 400, devices=(1, 2, 4, 8, 16),
     for n_dev in devices:
         env = dict(os.environ)
         env["PYTHONPATH"] = src + os.pathsep + env.get("PYTHONPATH", "")
+        env["JAX_PLATFORMS"] = "cpu"
         code = textwrap.dedent(_SCRIPT.format(n_dev=n_dev, n=n, k=k))
         p = subprocess.run([sys.executable, "-c", code], env=env,
                            capture_output=True, text=True, timeout=900)

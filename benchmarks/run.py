@@ -16,6 +16,9 @@ def main() -> None:
                     help="comma-separated benchmark names")
     args, _ = ap.parse_known_args()
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
+
     from . import (elastic_overhead, fig2_cores, fig34_scaling,
                    fig56_convergence, fleet_recovery, kshard_fused,
                    mc_fused, nystrom_fused, rng_fused, roofline,

@@ -33,14 +33,14 @@ sys.path.insert(0, os.path.join(os.path.dirname(__file__), "..", "src"))
 
 
 def main() -> int:
+    import jax
     import numpy as np
 
-    from repro import compat
     from repro.core import PEMSVM, SVMConfig
     from repro.core.nystrom import NystromSVM
 
-    mesh = compat.make_mesh((1, 2), ("data", "model"),
-                            axis_types=("auto",) * 2)
+    mesh = jax.make_mesh((1, 2), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     rng = np.random.default_rng(0)
     N, K = 1024, 23                    # +bias -> 24, model axis 2 | 24
     w_true = rng.normal(size=K)

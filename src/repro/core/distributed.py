@@ -32,7 +32,7 @@ import jax.numpy as jnp
 import numpy as np
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
-from repro.compat import shard_map
+from jax import shard_map
 
 from .linear import SVMData
 from .stats import shard_row_offset  # noqa: F401 — re-export (public API)

@@ -31,8 +31,6 @@ from typing import NamedTuple, Sequence
 
 import jax
 import jax.numpy as jnp
-
-from repro import compat
 from repro.kernels import ops
 from . import augment, objective, stats
 
@@ -166,7 +164,7 @@ def _k_block(width: int, axis_name: str):
     all-gather would rebuild a (K, n*(K//n)) matrix) and corrupt the
     posterior.
     """
-    n = compat.axis_size(axis_name)
+    n = jax.lax.axis_size(axis_name)
     if width % n != 0:
         raise ValueError(
             f"k_shard_axis {axis_name!r} of size {n} does not divide "

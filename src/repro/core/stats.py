@@ -20,8 +20,6 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro import compat
-
 
 def shard_row_offset(local_n: int, axes: Sequence[str]) -> jnp.ndarray:
     """Global row index of this shard's first row, inside shard_map.
@@ -37,7 +35,7 @@ def shard_row_offset(local_n: int, axes: Sequence[str]) -> jnp.ndarray:
         return jnp.int32(0)
     off = jnp.int32(0)
     for ax in axes:
-        off = off * compat.axis_size(ax) + jax.lax.axis_index(ax)
+        off = off * jax.lax.axis_size(ax) + jax.lax.axis_index(ax)
     return off * local_n
 
 
@@ -78,7 +76,7 @@ def preduce(x: jnp.ndarray, axes: Sequence[str] | None,
     # den psum is one fp32 scalar.
     num = jax.lax.psum(lv.astype(x.dtype) * x, tuple(axes))
     den = jax.lax.psum(lv.astype(jnp.float32), tuple(axes))
-    total = float(np.prod([compat.axis_size(a) for a in axes]))
+    total = float(np.prod([jax.lax.axis_size(a) for a in axes]))
     scale = total / jnp.maximum(den, 1.0)
     return num * scale.astype(num.dtype)
 

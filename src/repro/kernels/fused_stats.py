@@ -23,12 +23,12 @@ the kernel stays PRNG-free and the draws stay bitwise identical to the
 ``augment.gamma_mc_rowwise`` oracle (see ``epilogues.py``).
 
 Grid is 1-D over N-blocks; each step holds a (bn, K) X tile, the (K, 1)
-weight vector and the full (K, K) fp32 Sigma accumulator in VMEM. That
-accumulator bounds the usable K: K <= ~1500 fits the ~16 MB VMEM budget
-with bn=512 (K*K*4B + 2*bn*K*4B; the per-row noise/aug vectors add
-<= 6*bn*4B — noise). Larger K should use the split pair (two passes,
-tiled K). The SVM regime of the paper (K = 54..800 after bias) sits
-comfortably inside.
+weight vector and the full (K, K) fp32 Sigma accumulator in VMEM. With
+Pallas' double-buffered blocks that bounds the usable K under the
+default 16 MiB scoped VMEM: K <= 1024 at bn=512 by the accounting in
+``ops.fused_stats_fits`` (the compiler itself takes 1152). Larger K uses
+the split pair (tiled K). The SVM regime of the paper (K = 54..800
+after bias) sits inside.
 
 ``col_start``/``col_blk`` switch Sigma to a COLUMN-WINDOWED output
 S_blk = X^T diag(m*w) X[:, start:start+blk] — the 2-D (data x model)
@@ -37,10 +37,12 @@ accumulates only its (K, K/n) column block, margin/aug/b unchanged, so
 the 2-D layout keeps the one-X-stream property. ``col_blk`` is static
 (it shapes the accumulator); ``col_start`` is a TRACED scalar — inside
 ``shard_map`` it is ``axis_index * blk``, which no static argument can
-express. The kernel therefore loads the window with an in-VMEM dynamic
-slice of the X tile at a 128-ALIGNED traced base (the scalar rides in
-SMEM), over-fetching up to one lane-tile on each side; the wrapper
-slices the exact [start, start+blk) columns out of the aligned result.
+express. The kernel therefore loads the window from the X tile's VMEM
+ref at a 128-ALIGNED traced base (the scalar rides in SMEM; the TPU
+kernel compiler lowers an aligned ``pl.ds`` ref load, not a dynamic
+slice of a loaded value), over-fetching up to one lane-tile on each
+side; the wrapper slices the exact [start, start+blk) columns out of
+the aligned result.
 The narrowed (K, Cw) accumulator is what lets K beyond the full-width
 cap still fuse (``ops.fused_stats_fits``).
 
@@ -108,8 +110,8 @@ def _make_kernel(epilogue: str, eps: float, eps_ins: float,
             x, coef, dimension_numbers=(((0,), (0,)), ((), ())),
             preferred_element_type=jnp.float32)
         if windowed:                                 # aligned column window
-            xc = jax.lax.dynamic_slice(
-                x, (0, c0_ref[0]), (x.shape[0], s_ref.shape[1]))
+            a0 = pl.multiple_of(c0_ref[0], 128)
+            xc = x_ref[:, pl.ds(a0, s_ref.shape[1])].astype(jnp.float32)
         else:
             xc = x
         if n_chains == 1:

@@ -130,6 +130,8 @@ def _make_fused_kernel(kind: str, inv_two_sigma_sq: float,
         x_ref, lm_ref, pj_ref, mask_ref, rho_ref, beta_ref, w_ref = refs[:7]
         noise_refs = refs[7:7 + n_noise]
         outs = refs[7 + n_noise:]
+        if windowed:                    # trailing VMEM scratch: phi tile
+            outs, phi_ref = outs[:-1], outs[-1]
         margin_ref, aug_refs = outs[0], outs[1:1 + n_aug]
         b_ref, s_ref = outs[-2], outs[-1]
 
@@ -170,8 +172,11 @@ def _make_fused_kernel(kind: str, inv_two_sigma_sq: float,
             preferred_element_type=jnp.float32)
         pw = phi * (maskv * weight)                          # weighted rows
         if windowed:                    # aligned phi-column window, VMEM
-            pc = jax.lax.dynamic_slice(
-                phi, (0, c0_ref[0]), (phi.shape[0], s_ref.shape[1]))
+            # The TPU kernel compiler slices refs, not loaded values, at
+            # a traced offset: stage phi and load the window back.
+            phi_ref[...] = phi
+            a0 = pl.multiple_of(c0_ref[0], 128)
+            pc = phi_ref[:, pl.ds(a0, s_ref.shape[1])]
         else:
             pc = phi
         s_ref[...] += jax.lax.dot_general(                   # phi^T D phi_w
@@ -377,6 +382,8 @@ def nystrom_fused_stats(X: jnp.ndarray, landmarks: jnp.ndarray,
             jax.ShapeDtypeStruct((Wp, 1), jnp.float32),
             jax.ShapeDtypeStruct((Wp, Sw), jnp.float32),
         ],
+        scratch_shapes=([pltpu.VMEM((bn, Wp), jnp.float32)] if windowed
+                        else []),
         interpret=interpret,
     )(*extra_ops, X, landmarks, proj, mask.reshape(Np, 1),
       rho.reshape(Np, 1), beta.reshape(Np, 1), wvec.reshape(Wp, 1),

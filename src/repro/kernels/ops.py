@@ -12,6 +12,8 @@ benchmarks (ref → XLA) and TPU production (pallas).
 """
 from __future__ import annotations
 
+import functools
+
 import jax
 import jax.numpy as jnp
 
@@ -85,46 +87,60 @@ def syrk_tri(X: jnp.ndarray, w: jnp.ndarray, *,
     return _syrk.syrk_tri(X, w, interpret=(backend == "interpret"), **kw)
 
 
-# fused_stats holds the full (K, K) fp32 Sigma accumulator in VMEM;
-# past this K the tile no longer fits (~16 MB VMEM with the X tile) and
-# the kernel must not be attempted (DESIGN.md §Perf). Above it, the
-# K-tiled two-pass pair is the correct regime anyway (compute-bound).
-# The augmentation epilogues only add per-row (bn, 1) vectors (noise,
-# gamma/omega) — <= 6 * bn * 4 B, noise next to the K^2 accumulator —
-# so one cap serves every epilogue.
-FUSED_STATS_MAX_K = 1536
-_FUSED_STATS_VMEM_BUDGET = 14 * 2 ** 20
+def _ru(x: int, m: int) -> int:
+    return ((x + m - 1) // m) * m
 
 
-def _fused_stats_vmem_words(n_features: int, col_blk: int,
+# No kernel raises its VMEM limit, so each runs under the TPU
+# compiler's default scoped limit (16 MiB on v5e); a working set past
+# it is refused at compile time (RESOURCE_EXHAUSTED in vmem).
+_SCOPED_VMEM_BYTES = 16 * 2 ** 20
+
+
+def _fused_stats_vmem_bytes(n_features: int, col_blk: int | None,
                             block_n: int, epilogue: str,
-                            rng: bool = False) -> int:
-    """fp32 words resident per grid step of the COLUMN-WINDOWED fused
-    statistic (DESIGN.md §Perf/k-shard): the X tile, w/b, the narrowed
-    (Kp, Cw) Sigma accumulator, and the epilogue's per-row vectors
-    (rho/beta/wmask/margin + noise + aug). Under the in-kernel RNG
-    (``rng=True``) the noise operands are derived in registers — zero
-    resident words."""
+                            n_chains: int = 1, x_bytes: int = 4) -> int:
+    """Upper bound on the scoped VMEM of one ``fused_stats`` grid step
+    (DESIGN.md §Perf, "VMEM accounting"), as Pallas lays it out:
+
+      * every pipelined block twice (double-buffered): the (bn, Kp) X
+        tile, the (bn, 1) rho/beta/mask vectors and the (bn, C) margin
+        and aug outputs, the (Kp, C) w and b blocks — each narrow block
+        padded to 128 lanes;
+      * the epilogue's noise vectors twice as well — streamed operands,
+        or under the in-kernel RNG the same-shaped cipher temporaries;
+      * the (Kp, C*Cw) Sigma accumulator once, plus one (bn, Kp) f32
+        weighted-row temporary (and the f32 cast of a narrower X tile).
+
+    Checked against the compiler's own figures at the boundary (the
+    largest admitted shape compiles, ``tests/test_tpu_compile.py``)."""
     Kp = _ru(n_features, 128)
-    Cw = min(Kp, _ru(col_blk, 128) + 128)
-    per_row = (4 + (0 if rng else epilogues.noise_arity(epilogue))
-               + epilogues.aug_arity(epilogue))
-    return block_n * Kp + 2 * Kp + Kp * Cw + per_row * block_n
+    Cw = Kp if col_blk is None else min(Kp, _ru(col_blk, 128) + 128)
+    lanes = _ru(n_chains, 128)
+    rows = (3 * 128 + epilogues.noise_arity(epilogue) * lanes
+            + (1 + epilogues.aug_arity(epilogue)) * lanes)
+    streamed = block_n * Kp * x_bytes + 4 * (block_n * rows
+                                             + 2 * Kp * lanes)
+    temps = 4 * block_n * Kp * (1 + (x_bytes < 4))
+    return 2 * streamed + 4 * Kp * n_chains * Cw + temps
 
 
 def fused_stats_fits(n_features: int, col_blk: int | None = None,
                      block_n: int = 512,
                      epilogue: str = "em_hinge",
-                     rng: bool = False) -> bool:
-    """Whether the one-pass fused-statistic kernel's working set fits
-    VMEM. Full-width Sigma keeps the documented FUSED_STATS_MAX_K cap;
-    a column window narrows the accumulator to (K, Cw), so K beyond the
-    full cap can still fuse as long as the byte budget holds."""
-    if col_blk is None:
-        return n_features <= FUSED_STATS_MAX_K
-    return 4 * _fused_stats_vmem_words(
-        n_features, col_blk, block_n, epilogue,
-        rng) <= _FUSED_STATS_VMEM_BUDGET
+                     n_chains: int = 1, x_bytes: int = 4) -> bool:
+    """Whether the one-pass fused-statistic kernel fits the default
+    scoped VMEM limit. A column window narrows the accumulator to
+    (K, Cw), so K beyond the full-width cap can still fuse."""
+    return _fused_stats_vmem_bytes(n_features, col_blk, block_n,
+                                   epilogue, n_chains,
+                                   x_bytes) <= _SCOPED_VMEM_BYTES
+
+
+# Largest full-width K (a lane multiple) the single-chain kernel takes
+# at the default block: past it the dispatch uses the split fallback.
+FUSED_STATS_MAX_K = max(k for k in range(128, 4096, 128)
+                        if fused_stats_fits(k))
 
 
 def fused_stats(X: jnp.ndarray, rho: jnp.ndarray, beta: jnp.ndarray,
@@ -152,8 +168,9 @@ def fused_stats(X: jnp.ndarray, rho: jnp.ndarray, beta: jnp.ndarray,
     ``k_shard_axis`` statistic stays single-stream: ``blk`` is static,
     ``start`` may be traced (``axis_index * blk`` inside shard_map).
 
-    For K > FUSED_STATS_MAX_K (full width; C*K for C chains) or past
-    the windowed byte budget (``fused_stats_fits``) the Pallas flavors
+    When the working set exceeds the scoped VMEM limit
+    (``fused_stats_fits``: full width past FUSED_STATS_MAX_K, fewer K
+    for C chains, or a window too wide) the Pallas flavors
     fall back to the K-tiled split pair (E-step + syrk_tri; windowed:
     plain-XLA column block) rather than blow VMEM — callers get the
     same outputs either way."""
@@ -166,11 +183,13 @@ def fused_stats(X: jnp.ndarray, rho: jnp.ndarray, beta: jnp.ndarray,
                                epilogue=epilogue, noise=noise,
                                eps_ins=eps_ins, col_window=col_window,
                                seed=seed)
+    fits = functools.partial(
+        fused_stats_fits, X.shape[1], block_n=kw.get("block_n", 512),
+        epilogue=epilogue, n_chains=n_chains,
+        x_bytes=jnp.dtype(X.dtype).itemsize)
     if col_window is not None:
         start, blk = col_window
-        if not fused_stats_fits(X.shape[1], blk,
-                                kw.get("block_n", 512), epilogue,
-                                seed is not None):
+        if not fits(blk):
             # Windowed split fallback: the narrowed Sigma block is a
             # plain (weighted X)^T Xcols matmul XLA tiles itself —
             # the compute-bound regime where stream count stops being
@@ -184,7 +203,7 @@ def fused_stats(X: jnp.ndarray, rho: jnp.ndarray, beta: jnp.ndarray,
             X, rho, beta, wvec, wmask, noise, start, seed,
             epilogue=epilogue, eps=eps, eps_ins=eps_ins, col_blk=blk,
             interpret=(backend == "interpret"), **kw)
-    if X.shape[1] * n_chains > FUSED_STATS_MAX_K:
+    if not fits(None):
         kw.pop("block_n", None)
         if multi:
             # Multichain past the VMEM cap: the C stacked Sigma blocks
@@ -192,15 +211,11 @@ def fused_stats(X: jnp.ndarray, rho: jnp.ndarray, beta: jnp.ndarray,
             return ref.fused_stats(X, rho, beta, wvec, wmask, eps,
                                    epilogue=epilogue, noise=noise,
                                    eps_ins=eps_ins, seed=seed)
-        if epilogue == "em_hinge":
-            margin, gamma, b = fused_estep(X, rho, beta, wvec, eps=eps,
-                                           backend=backend)
-            w = (1.0 / gamma) if wmask is None else wmask / gamma
-            return margin, gamma, b, syrk_tri(X, w, backend=backend)
-        # Generalized split fallback: the O(NK) E-step (margin, aug,
-        # coef) runs as plain XLA; only the O(NK^2) Sigma goes through
-        # the K-tiled SYRK kernel. 3 X streams — the compute-bound
-        # regime where stream count stops being the bound anyway.
+        # Split fallback: the O(NK) E-step (margin, aug, coef) runs as
+        # plain XLA; only the O(NK^2) Sigma goes through the K-tiled
+        # SYRK kernel, whose blocks fit VMEM at any K. 3 X streams —
+        # the compute-bound regime where stream count stops being the
+        # bound anyway.
         if seed is not None:
             noise = ref.seed_noise(seed, X.shape[0], 1, epilogue)
         Xf = X.astype(jnp.float32)
@@ -249,10 +264,6 @@ NYSTROM_FUSED_MAX_M = 1024
 _NYSTROM_VMEM_BUDGET = 14 * 2 ** 20
 
 
-def _ru(x: int, m: int) -> int:
-    return ((x + m - 1) // m) * m
-
-
 def _nystrom_vmem_words(n_landmarks: int, n_features: int, add_bias: bool,
                         block_n: int, with_stats: bool,
                         epilogue: str = "em_hinge",
@@ -280,6 +291,8 @@ def _nystrom_vmem_words(n_landmarks: int, n_features: int, add_bias: bool,
         Cw = Wp if col_blk is None else min(Wp, _ru(col_blk, 128) + 128)
         words += (Wp * Cw        # Sigma accumulator (windowed: narrowed)
                   + Wp + per_row * block_n)  # w/b + per-row vectors
+        if col_blk is not None:
+            words += block_n * Wp  # phi staged for the window load
     return words
 
 

@@ -84,8 +84,11 @@ def uniform_from_bits(bits):
     ``(i + 0.5) * 2^-23`` for i in [0, 2^23), i.e. in
     [2^-24, 1 - 2^-24] -- never 0 or 1, so the Box-Muller log below
     stays finite.  ``(i + 0.5) * c`` is add-then-mul, not an FMA shape.
+    The int -> float conversion goes through int32 because the TPU
+    kernel compiler has no uint32 -> float32 cast; i < 2^23, so the
+    detour is exact.
     """
-    i = (bits >> _U32(9)).astype(jnp.float32)
+    i = (bits >> _U32(9)).astype(jnp.int32).astype(jnp.float32)
     return (i + jnp.float32(0.5)) * jnp.float32(2.0 ** -23)
 
 

@@ -1,12 +1,16 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 
 """Multi-pod dry-run: lower + compile every (architecture x input-shape)
 cell on the production mesh and extract the roofline terms.
 
-The two lines above run before ANY other import — jax locks the device
-count at first init, and the dry-run (and only the dry-run) needs 512
-placeholder host devices to build the 16x16 / 2x16x16 meshes.
+A CPU emulation by design. The lines above run before ANY other import
+— jax locks the device count at first init, and the dry-run (and only
+the dry-run) needs 512 placeholder host devices to build the 16x16 /
+2x16x16 meshes. It is pinned to the CPU backend so that, on a machine
+with a TPU, it (and the sweep/hill-climb children that run it) never
+takes the chip or blocks on the TPU runtime's one-process lock.
 
 Usage:
   python -m repro.launch.dryrun --arch yi-34b --shape train_4k \
@@ -25,8 +29,6 @@ import traceback  # noqa: E402
 import jax  # noqa: E402
 import jax.numpy as jnp  # noqa: E402
 from jax.sharding import NamedSharding, PartitionSpec as P  # noqa: E402
-
-from repro import compat  # noqa: E402
 from repro.configs import SHAPES, applicable, get_config  # noqa: E402
 from repro.launch import hlo_cost  # noqa: E402
 from repro.launch import specs as sp  # noqa: E402
@@ -176,7 +178,7 @@ def run_cell(arch: str, shape_name: str, multi_pod: bool,
     try:
         mesh, fn, args, in_sh, out_sh, donate = build_cell(
             arch, shape_name, multi_pod, opts)
-        with compat.set_mesh(mesh):
+        with jax.set_mesh(mesh):
             if is_svm:     # svm cells arrive pre-wrapped by shard_map
                 jitted = fn
             else:

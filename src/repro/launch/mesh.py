@@ -6,15 +6,18 @@ before its first jax import; anything at module scope here would lock the
 device count prematurely)."""
 from __future__ import annotations
 
-from repro.compat import make_mesh
+import jax
+from jax.sharding import AxisType
 
 
 def make_production_mesh(*, multi_pod: bool = False):
     shape = (2, 16, 16) if multi_pod else (16, 16)
     axes = ("pod", "data", "model") if multi_pod else ("data", "model")
-    return make_mesh(shape, axes, axis_types=("auto",) * len(axes))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_host_mesh(shape=(2, 2), axes=("data", "model")):
     """Small mesh over forced host devices (tests)."""
-    return make_mesh(shape, axes, axis_types=("auto",) * len(axes))
+    return jax.make_mesh(shape, axes,
+                         axis_types=(AxisType.Auto,) * len(axes))

@@ -89,6 +89,8 @@ def main():
                     choices=["linear", "nystrom"])
     args = ap.parse_args()
 
+    from repro.compile_cache import enable_compile_cache
+    enable_compile_cache()
     if args.mode == "svm":
         main_svm(args)
         return

@@ -53,6 +53,7 @@ def main():
     from jax.sharding import NamedSharding, PartitionSpec as P
 
     from repro.checkpoint import Checkpointer
+    from repro.compile_cache import enable_compile_cache
     from repro.configs import get_config
     from repro.data import ShardedBatcher, make_lm_tokens
     from repro.launch.mesh import make_host_mesh, make_production_mesh
@@ -61,6 +62,8 @@ def main():
     from repro.runtime import StepTimeMonitor
     from repro.sharding import ShardingCtx, param_specs
     from repro.training import (AdamWConfig, init_state, make_train_step)
+
+    enable_compile_cache()
 
     cfg = get_config(args.arch)
     if args.preset == "tiny":

@@ -116,7 +116,7 @@ def seq_parallel_attention(ctx, q, k, v, *, causal=True, q_chunk=1024,
     blockwise attention when S doesn't divide."""
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import shard_map
+    from jax import shard_map
 
     B, S = q.shape[0], q.shape[1]
     tp = ctx.tp_axis
@@ -157,9 +157,7 @@ def decode_attn_island(ctx, q, k_cache, v_cache, pos, k_new, v_new):
     q/k_new/v_new: (B, 1, H|KVH, dh); caches: (B, S, KVH, dh).
     Returns (attn out (B, 1, H, dh), new k_cache, new v_cache)."""
     from jax.sharding import PartitionSpec as P
-
-    from repro import compat  # local import: cycle-free
-    from repro.compat import shard_map
+    from jax import shard_map
 
     B, S, KVH, _ = k_cache.shape
     H, dh = q.shape[2], q.shape[3]
@@ -180,7 +178,7 @@ def decode_attn_island(ctx, q, k_cache, v_cache, pos, k_new, v_new):
         S_loc = kc.shape[1]
         off = jnp.int32(0)
         for a in seq_axes:
-            off = off * compat.axis_size(a) + jax.lax.axis_index(a)
+            off = off * jax.lax.axis_size(a) + jax.lax.axis_index(a)
         start = off * S_loc
         rel = pos_ - start
         ok = (rel >= 0) & (rel < S_loc)

@@ -238,6 +238,12 @@ class SubprocessHost:
     ``load_result()`` (if given) produces the value returned to the
     controller — e.g. reading the weights the program wrote, or loading
     the final snapshot from the shared checkpoint directory.
+
+    One process per chip: a TPU belongs to the one process that opened
+    it. A controller whose own process has touched JAX on a TPU machine
+    holds the chip, so its children cannot get it (they fail or block
+    on the TPU runtime's lock). Run such a controller off JAX, or give
+    the children ``JAX_PLATFORMS=cpu`` through ``env``.
     """
 
     def __init__(self, code: str, *, env: dict | None = None,

@@ -25,11 +25,10 @@ def run_with_devices(code: str, n_devices: int = 8, timeout: int = 600):
 
 HEADER = """
 import numpy as np, jax, jax.numpy as jnp
-from repro import compat
 from jax.sharding import PartitionSpec as P
 from repro.core import PEMSVM, SVMConfig
-mesh = compat.make_mesh((4, 2), ("data", "model"),
-                     axis_types=("auto",) * 2)
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 rng = np.random.default_rng(0)
 N, K = 1037, 23
 w_true = rng.normal(size=K)
@@ -136,10 +135,9 @@ def test_krn_mc_chain_is_mesh_layout_invariant():
     (8 and 64) so the two runs see identical padded shapes."""
     run_with_devices("""
 import numpy as np, jax, jax.numpy as jnp
-from repro import compat
 from repro.core import PEMSVM, SVMConfig
-mesh = compat.make_mesh((4, 2), ("data", "model"),
-                     axis_types=("auto",) * 2)
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 rng = np.random.default_rng(0)
 N = 320
 r_ = np.concatenate([rng.uniform(0, 1, N // 2),
@@ -165,11 +163,11 @@ def test_nystrom_mesh_matches_single_device():
     featurizer arrays ride the replicated prior slot, and the EM fit
     matches the single-device one."""
     run_with_devices("""
+import jax
 import numpy as np
-from repro import compat
 from repro.core import NystromSVM, SVMConfig
-mesh = compat.make_mesh((4, 2), ("data", "model"),
-                     axis_types=("auto",) * 2)
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 rng = np.random.default_rng(0)
 N, D = 1024, 12
 X = rng.normal(size=(N, D)).astype(np.float32)
@@ -207,12 +205,11 @@ else:
 def test_live_weighted_psum_drops_dead_replica():
     run_with_devices("""
 import numpy as np, jax, jax.numpy as jnp
-from repro import compat
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
 from repro.core.distributed import live_weighted_psum
-mesh = compat.make_mesh((8,), ("data",),
-                     axis_types=("auto",))
+mesh = jax.make_mesh((8,), ("data",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 def f(x, live):
     return live_weighted_psum(x, live, ("data",))
 g = jax.jit(shard_map(f, mesh=mesh, in_specs=(P("data"), P("data")),
@@ -229,12 +226,12 @@ np.testing.assert_allclose(out, want, rtol=1e-6)
 def test_elastic_remesh_roundtrip():
     run_with_devices("""
 import numpy as np, jax, jax.numpy as jnp
-from repro import compat
 from jax.sharding import NamedSharding, PartitionSpec as P
 from repro.runtime import remesh, scale_batch_schedule
-m1 = compat.make_mesh((8,), ("data",), axis_types=("auto",))
-m2 = compat.make_mesh((4, 2), ("data", "model"),
-                   axis_types=("auto",) * 2)
+m1 = jax.make_mesh((8,), ("data",),
+                   axis_types=(jax.sharding.AxisType.Auto,))
+m2 = jax.make_mesh((4, 2), ("data", "model"),
+                   axis_types=(jax.sharding.AxisType.Auto,) * 2)
 tree = {"w": jnp.arange(64.0).reshape(8, 8)}
 t1 = jax.device_put(tree, NamedSharding(m1, P("data", None)))
 t2 = remesh(t1, {"w": NamedSharding(m2, P("model", "data"))})
@@ -250,11 +247,10 @@ assert gb == 512 and lr == 2.0
 def test_seq_parallel_attention_matches_blockwise():
     run_with_devices("""
 import numpy as np, jax, jax.numpy as jnp
-from repro import compat
 from repro.models.attention import blockwise_attn, seq_parallel_attention
 from repro.sharding import ShardingCtx
-mesh = compat.make_mesh((2, 4), ("data", "model"),
-                     axis_types=("auto",) * 2)
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 ctx = ShardingCtx(mesh=mesh, dp_axes=("data",), tp_axis="model",
                   fsdp_axis="data")
 key = jax.random.PRNGKey(0)
@@ -263,7 +259,7 @@ q = jax.random.normal(key, (B, S, H, dh))
 k = jax.random.normal(jax.random.PRNGKey(1), (B, S, KVH, dh))
 v = jax.random.normal(jax.random.PRNGKey(2), (B, S, KVH, dh))
 ref = blockwise_attn(q, k, v, causal=True, q_chunk=16, kv_chunk=16)
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     got = jax.jit(lambda a, b, c: seq_parallel_attention(
         ctx, a, b, c, causal=True, q_chunk=16, kv_chunk=16))(q, k, v)
 np.testing.assert_allclose(np.asarray(got), np.asarray(ref), rtol=2e-4,
@@ -275,11 +271,10 @@ print("seq-parallel attention OK")
 def test_decode_island_matches_dense_decode():
     run_with_devices("""
 import numpy as np, jax, jax.numpy as jnp
-from repro import compat
 from repro.models.attention import decode_attn, decode_attn_island
 from repro.sharding import ShardingCtx
-mesh = compat.make_mesh((2, 4), ("data", "model"),
-                     axis_types=("auto",) * 2)
+mesh = jax.make_mesh((2, 4), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 ctx = ShardingCtx(mesh=mesh, dp_axes=("data",), tp_axis="model",
                   fsdp_axis="data")
 key = jax.random.PRNGKey(0)
@@ -294,7 +289,7 @@ vn = jax.random.normal(jax.random.PRNGKey(4), (B, 1, KVH, dh))
 kc_ref = jax.lax.dynamic_update_slice_in_dim(kc, kn, pos, axis=1)
 vc_ref = jax.lax.dynamic_update_slice_in_dim(vc, vn, pos, axis=1)
 ref = decode_attn(q, kc_ref, vc_ref, pos + 1)
-with compat.set_mesh(mesh):
+with jax.set_mesh(mesh):
     o, kc2, vc2 = jax.jit(lambda *a: decode_attn_island(ctx, *a))(
         q, kc, vc, jnp.int32(pos), kn, vn)
 np.testing.assert_allclose(np.asarray(o), np.asarray(ref), rtol=2e-4,

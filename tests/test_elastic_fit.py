@@ -313,14 +313,15 @@ def run_with_devices(code: str, n_devices: int = 4, timeout: int = 600):
 
 
 MESH_HEADER = """
+import jax
 import numpy as np, tempfile
-from repro import compat
 from repro.core import PEMSVM, SVMConfig
 from repro.runtime.policy import FaultPolicy, StragglerError
 from repro.runtime import faults
-mesh_a = compat.make_mesh((2, 2), ("data", "model"),
-                          axis_types=("auto",) * 2)
-mesh_b = compat.make_mesh((4,), ("data",), axis_types=("auto",))
+mesh_a = jax.make_mesh((2, 2), ("data", "model"),
+                       axis_types=(jax.sharding.AxisType.Auto,) * 2)
+mesh_b = jax.make_mesh((4,), ("data",),
+                       axis_types=(jax.sharding.AxisType.Auto,))
 rng = np.random.default_rng(0)
 N, K = 512, 23
 w_true = rng.normal(size=K)

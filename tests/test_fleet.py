@@ -408,17 +408,18 @@ def test_fleet_forced_remesh_within_band():
     documented EM cross-mesh reassociation band of the uninterrupted
     flat-mesh fit."""
     run_with_devices("""
+import jax
 import numpy as np, tempfile
-from repro import compat
 from repro.core import PEMSVM, SVMConfig
 from repro.runtime import faults
 from repro.runtime.controller import FleetController, FleetPolicy
 from repro.runtime.faults import FleetSchedule
 from repro.runtime.policy import FaultPolicy
 
-mesh_a = compat.make_mesh((2, 2), ("data", "model"),
-                          axis_types=("auto",) * 2)
-mesh_b = compat.make_mesh((4,), ("data",), axis_types=("auto",))
+mesh_a = jax.make_mesh((2, 2), ("data", "model"),
+                       axis_types=(jax.sharding.AxisType.Auto,) * 2)
+mesh_b = jax.make_mesh((4,), ("data",),
+                       axis_types=(jax.sharding.AxisType.Auto,))
 rng = np.random.default_rng(0)
 N, K = 512, 23
 w_true = rng.normal(size=K)

@@ -118,8 +118,9 @@ def test_fused_stats_large_k_falls_back_to_split():
     X, _, y, _ = _data(n, 8, np.float32)
     Xw = jnp.asarray(RNG.normal(size=(n, k)).astype(np.float32))
     wv = jnp.asarray(RNG.normal(size=k).astype(np.float32))
+    assert not ops.fused_stats_fits(k)
     got = ops.fused_stats(Xw, jnp.asarray(y), jnp.asarray(y), wv,
-                          eps=1e-6, backend="interpret", block_n=32)
+                          eps=1e-6, backend="interpret")
     want = ref.fused_stats(Xw, jnp.asarray(y), jnp.asarray(y), wv,
                            None, 1e-6)
     for g, w_, name in zip(got, want, ("margin", "gamma", "b", "S")):

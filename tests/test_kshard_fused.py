@@ -169,7 +169,7 @@ def test_pad_features_to():
 
 
 def test_k_block_error_names_the_pad_helper():
-    from repro.compat import make_mesh, shard_map
+    from jax import make_mesh, shard_map
     from jax.sharding import PartitionSpec as P
 
     from repro.core.linear import _k_block
@@ -185,14 +185,13 @@ def test_k_block_error_names_the_pad_helper():
     # the real refusal needs axis size > 1 -> exercised in the
     # subprocess tests below; here check the message contract directly
     import repro.core.linear as linear_mod
-    import repro.compat as compat_mod
-    orig = compat_mod.axis_size
+    orig = jax.lax.axis_size
     try:
-        compat_mod.axis_size = lambda a: 2
+        jax.lax.axis_size = lambda a: 2
         with pytest.raises(ValueError) as ei:
             linear_mod._k_block(7, "model")
     finally:
-        compat_mod.axis_size = orig
+        jax.lax.axis_size = orig
     msg = str(ei.value)
     assert "does not divide" in msg
     assert "pad_features_to" in msg
@@ -213,10 +212,9 @@ def run_with_devices(code: str, n_devices: int = 4, timeout: int = 600):
 
 HEADER = """
 import numpy as np, jax, jax.numpy as jnp
-from repro import compat
 from repro.core import PEMSVM, SVMConfig
-mesh = compat.make_mesh((2, 2), ("data", "model"),
-                        axis_types=("auto",) * 2)
+mesh = jax.make_mesh((2, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 rng = np.random.default_rng(0)
 N, K = 1024, 23                       # +bias -> 24, model axis 2 | 24
 w_true = rng.normal(size=K)
@@ -272,8 +270,8 @@ def test_kshard_mesh_layout_invariance():
     laid out: (2, 2) and (1, 4) (data x model) give the same chain up
     to fp32 psum reassociation."""
     run_with_devices(HEADER + """
-mesh14 = compat.make_mesh((1, 4), ("data", "model"),
-                          axis_types=("auto",) * 2)
+mesh14 = jax.make_mesh((1, 4), ("data", "model"),
+                       axis_types=(jax.sharding.AxisType.Auto,) * 2)
 cfg = dict(task="CLS", algorithm="MC", max_iters=10, min_iters=10,
            eps=1e-2, burnin=5)
 a = PEMSVM(SVMConfig(k_shard_axis="model", **cfg), mesh=mesh,

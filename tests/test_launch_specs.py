@@ -2,8 +2,6 @@
 sweep iterations are pinned here)."""
 import jax
 import jax.numpy as jnp
-
-from repro import compat
 import pytest
 
 from repro.configs import SHAPES, get_config
@@ -14,8 +12,8 @@ from repro.sharding import ShardingCtx
 @pytest.fixture(scope="module")
 def ctx1():
     # single-device mesh: divisibility checks still exercise the code
-    mesh = compat.make_mesh((1, 1), ("data", "model"),
-                         axis_types=("auto",) * 2)
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(jax.sharding.AxisType.Auto,) * 2)
     return ShardingCtx(mesh=mesh, dp_axes=("data",), tp_axis="model",
                        fsdp_axis="data")
 

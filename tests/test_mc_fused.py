@@ -318,8 +318,9 @@ def test_mc_epilogue_large_k_falls_back_to_split():
     y = jnp.asarray(rng.choice([-1.0, 1.0], n).astype(np.float32))
     wv = jnp.asarray(rng.normal(size=k).astype(np.float32))
     noise = augment.draw_ig_noise(jax.random.PRNGKey(0), n, 0)
+    assert not ops.fused_stats_fits(k, epilogue="mc_hinge")
     got = ops.fused_stats(X, y, y, wv, None, noise, epilogue="mc_hinge",
-                          eps=1e-6, backend="interpret", block_n=32)
+                          eps=1e-6, backend="interpret")
     want = ref.fused_stats(X, y, y, wv, None, 1e-6, epilogue="mc_hinge",
                            noise=noise)
     np.testing.assert_array_equal(np.asarray(got[1]), np.asarray(want[1]))
@@ -352,10 +353,9 @@ def test_mc_cls_svr_chain_is_mesh_layout_invariant():
     layout-invariant (the means differ only by psum ordering)."""
     _run_with_devices("""
 import numpy as np, jax
-from repro import compat
 from repro.core import PEMSVM, SVMConfig
-mesh = compat.make_mesh((4, 2), ("data", "model"),
-                        axis_types=("auto",) * 2)
+mesh = jax.make_mesh((4, 2), ("data", "model"),
+                     axis_types=(jax.sharding.AxisType.Auto,) * 2)
 rng = np.random.default_rng(0)
 N, K = 1024, 16
 X = rng.normal(size=(N, K)).astype(np.float32)
@@ -390,12 +390,12 @@ def test_k_shard_mc_casts_targets_to_f32():
 import jax
 jax.config.update("jax_enable_x64", True)
 import numpy as np, jax.numpy as jnp
-from repro import compat
 from jax.sharding import PartitionSpec as P
-from repro.compat import shard_map
+from jax import shard_map
 from repro.core import linear
 from repro.core.linear import SVMData
-mesh = compat.make_mesh((2,), ("model",), axis_types=("auto",))
+mesh = jax.make_mesh((2,), ("model",),
+                     axis_types=(jax.sharding.AxisType.Auto,))
 rng = np.random.default_rng(0)
 N, K = 64, 8
 X = jnp.asarray(rng.normal(size=(N, K)).astype(np.float32))

@@ -155,12 +155,11 @@ def test_lm_tokens_learnable_structure():
 # --------------------------------------------------------------- sharding
 def test_param_spec_divisibility_filter():
     import jax as _jax
-    from repro import compat as _compat
     devs = _jax.devices()
     if len(devs) < 1:
         return
-    mesh = _compat.make_mesh((1, 1), ("data", "model"),
-                          axis_types=("auto",) * 2)
+    mesh = _jax.make_mesh((1, 1), ("data", "model"),
+                          axis_types=(jax.sharding.AxisType.Auto,) * 2)
     ctx = ShardingCtx(mesh=mesh, dp_axes=("data",), tp_axis="model",
                       fsdp_axis="data")
     # divisible: sharded; mesh axes are size 1 so everything divides —
@@ -177,11 +176,10 @@ def test_param_spec_divisibility_filter():
 
 def test_spec_drops_non_divisible():
     import jax as _jax
-    from repro import compat as _compat
     if len(_jax.devices()) != 1:
         return
-    mesh = _compat.make_mesh((1,), ("data",),
-                          axis_types=("auto",))
+    mesh = _jax.make_mesh((1,), ("data",),
+                          axis_types=(jax.sharding.AxisType.Auto,))
     ctx = ShardingCtx(mesh=mesh, dp_axes=("data",), tp_axis=None,
                       fsdp_axis="data")
     # everything divides by 1; exercise the API contract

@@ -323,13 +323,13 @@ def test_fused_fit_is_mesh_layout_invariant():
     (gamma_mean to psum-reassociation tolerance at w=0, where margins
     are exactly zero on every layout)."""
     _run_with_devices("""
+import jax
 import numpy as np
-from repro import compat
 from repro.core import PEMSVM, SVMConfig
-mesh_a = compat.make_mesh((2, 2), ("data", "model"),
-                          axis_types=("auto",) * 2)
-mesh_b = compat.make_mesh((1, 4), ("model", "data"),
-                          axis_types=("auto",) * 2)
+mesh_a = jax.make_mesh((2, 2), ("data", "model"),
+                       axis_types=(jax.sharding.AxisType.Auto,) * 2)
+mesh_b = jax.make_mesh((1, 4), ("model", "data"),
+                       axis_types=(jax.sharding.AxisType.Auto,) * 2)
 rng = np.random.default_rng(0)
 N, K = 512, 16
 Xm = rng.normal(size=(N, K)).astype(np.float32)

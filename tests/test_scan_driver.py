@@ -145,7 +145,7 @@ def test_k_shard_indivisible_K_raises():
     import jax.numpy as jnp
     from jax.sharding import PartitionSpec as P
 
-    from repro.compat import make_mesh, shard_map
+    from jax import make_mesh, shard_map
     from repro.core.linear import _k_block
 
     mesh = make_mesh((1,), ("model",))
