@@ -1,0 +1,54 @@
+"""The work counts behind the roofline and MFU metrics, against values
+worked out by hand, and the table of peaks."""
+import sys
+from pathlib import Path
+
+import pytest
+
+sys.path.insert(0, str(Path(__file__).resolve().parents[2]))
+
+from bench import harness, peaks  # noqa: E402
+
+roofline = harness.load_metric("fused_stats_roofline")
+mfu = harness.load_metric("step_mfu")
+V5E = peaks.peak("TPU v5 lite")
+
+
+def test_dna_per_chip():
+    n, k = 1_048_576, 801
+    assert mfu.sigma_flops(n, k) == pytest.approx(6.74e11, rel=1e-3)
+    # one float32 read of X dominates the call's bytes
+    assert 4.0 * n * k == pytest.approx(3.36e9, rel=1e-3)
+    assert roofline.call_bytes(n, k) == pytest.approx(
+        4 * n * k + 20 * n + 8 * k + 4 * k * k)
+    least, bound = roofline.least_seconds(n, k, V5E)
+    assert bound == "memory"
+    assert least == pytest.approx(roofline.call_bytes(n, k) / 819e9)
+    assert least == pytest.approx(4.13e-3, rel=1e-2)
+
+
+def test_mnist8m_per_class_pass():
+    n, k = 524_288, 785
+    assert mfu.sigma_flops(n, k) == pytest.approx(3.24e11, rel=2e-3)
+    assert 4.0 * n * k == pytest.approx(1.65e9, rel=3e-3)
+    assert roofline.call_flops(n, k) == pytest.approx(
+        n * k * (k + 1) + 4 * n * k)
+    _, bound = roofline.least_seconds(n, k, V5E)
+    assert bound == "memory"
+
+
+def test_iteration_flops_count_every_class_pass_once():
+    job = harness.resolve("mnist8m-fit", 1)
+    n, k = 524_288, 785
+    one = n * k * (k + 1) + 4 * n * k + k ** 3 / 3 + 2 * k ** 2
+    assert mfu.iteration_flops(job) == pytest.approx(10 * one)
+    dp4 = harness.resolve("dna-fit-dp4", 1)
+    n = 4 * dp4.rows_per_chip
+    assert mfu.iteration_flops(dp4) == pytest.approx(
+        n * 801 * 802 + 4 * n * 801 + 801 ** 3 / 3 + 2 * 801 ** 2)
+
+
+def test_unknown_device_kind_raises():
+    with pytest.raises(KeyError, match="no published peak"):
+        peaks.peak("TPU v9 imaginary")
+    assert V5E.flops_per_s == 197e12 and V5E.hbm_bytes_per_s == 819e9
