@@ -30,6 +30,7 @@ from typing import Callable, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from jax import shard_map
@@ -55,10 +56,12 @@ def pad_rows(X: np.ndarray, target: np.ndarray, shards: int,
     chunk = shards * multiple
     Np = ((N + chunk - 1) // chunk) * chunk
     pad = Np - N
-    Xp = np.concatenate([X, np.zeros((pad,) + X.shape[1:], X.dtype)], axis=0)
-    tp = np.concatenate([target, np.zeros((pad,), target.dtype)], axis=0)
-    mask = np.concatenate([np.ones((N,), np.float32),
-                           np.zeros((pad,), np.float32)], axis=0)
+    with TraceAnnotation("pemsvm.pad_rows", rows=N, pad_rows=pad):
+        Xp = np.concatenate([X, np.zeros((pad,) + X.shape[1:], X.dtype)],
+                            axis=0)
+        tp = np.concatenate([target, np.zeros((pad,), target.dtype)], axis=0)
+        mask = np.concatenate([np.ones((N,), np.float32),
+                               np.zeros((pad,), np.float32)], axis=0)
     return Xp, tp, mask
 
 
@@ -73,12 +76,13 @@ def shard_rows(mesh: Mesh, axes: Sequence[str], X: np.ndarray,
     shards = num_shards(mesh, axes)
     Xp, tp, mask = pad_rows(X, target, shards)
     row_spec = P(tuple(axes))
-    data = SVMData(
-        X=jax.device_put(Xp, NamedSharding(mesh, P(tuple(axes), None))),
-        target=jax.device_put(tp, NamedSharding(mesh, row_spec)),
-        mask=jax.device_put(mask, NamedSharding(mesh, row_spec)),
-    )
-    return data
+    with TraceAnnotation("pemsvm.upload",
+                         bytes=Xp.nbytes + tp.nbytes + mask.nbytes):
+        return SVMData(
+            X=jax.device_put(Xp, NamedSharding(mesh, P(tuple(axes), None))),
+            target=jax.device_put(tp, NamedSharding(mesh, row_spec)),
+            mask=jax.device_put(mask, NamedSharding(mesh, row_spec)),
+        )
 
 
 def shard_wrap(mesh: Mesh, axes: Sequence[str],

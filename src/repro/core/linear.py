@@ -250,7 +250,8 @@ def cls_step(data: SVMData, w: jnp.ndarray, key: jax.Array, *,
                                    reduce_dtype=reduce_dtype, live=live)
 
     if multi:
-        w_new = multichain_draw(key, S, b, lam, jitter, chain0)
+        with jax.named_scope("mstep"):
+            w_new = multichain_draw(key, S, b, lam, jitter, chain0)
         maskc = jnp.broadcast_to(mask[:, None], margin.shape)
         obj = objective.l2_reg(w_new, lam) / n_chains + stats.preduce(
             objective.hinge_obj_terms(margin, y[:, None], maskc),
@@ -259,13 +260,15 @@ def cls_step(data: SVMData, w: jnp.ndarray, key: jax.Array, *,
                              axes, live) / n_chains
         gamma_mean = stats.masked_mean(gamma, maskc, axes, live)
     else:
-        L, mu = stats.posterior_params(S, b, lam, jitter=jitter)
-        if mode == "EM":
-            w_new = mu
-        elif rng == "host":
-            w_new = stats.draw_weight(key, L, mu)
-        else:
-            w_new = stats.draw_weight(chain_keys(key, chain0, 1)[0], L, mu)
+        with jax.named_scope("mstep"):
+            L, mu = stats.posterior_params(S, b, lam, jitter=jitter)
+            if mode == "EM":
+                w_new = mu
+            elif rng == "host":
+                w_new = stats.draw_weight(key, L, mu)
+            else:
+                w_new = stats.draw_weight(chain_keys(key, chain0, 1)[0],
+                                          L, mu)
         obj = objective.l2_reg(w_new, lam) + stats.preduce(
             objective.hinge_obj_terms(margin, y, mask), axes, live)
         n_sv = stats.preduce(jnp.sum(mask * (gamma <= 2.0 * eps)),

@@ -159,7 +159,8 @@ def mlt_step(data: SVMData, W: jnp.ndarray, key: jax.Array, *,
     col_window = (_k_block(W.shape[1], k_shard_axis)
                   if k_shard_axis is not None else None)
 
-    F0 = Xf @ W.T.astype(jnp.float32)                    # (N, M)
+    with jax.named_scope("score"):
+        F0 = Xf @ W.T.astype(jnp.float32)                # (N, M)
 
     def body(y, carry):
         W, F = carry
@@ -175,16 +176,18 @@ def mlt_step(data: SVMData, W: jnp.ndarray, key: jax.Array, *,
         else:
             S, b = stats.reduce_kshard(S, b, axes, k_shard_axis,
                                        reduce_dtype=reduce_dtype, live=live)
-        L, mu = stats.posterior_params(S, b, lam, jitter=jitter)
-        if mode == "EM":
-            w_new = mu
-        else:
-            ky = jax.random.fold_in(key, y)
-            if rng != "host":
-                ky = jax.random.fold_in(ky, chain0)
-            w_new = stats.draw_weight(ky, L, mu)
+        with jax.named_scope("mstep"):
+            L, mu = stats.posterior_params(S, b, lam, jitter=jitter)
+            if mode == "EM":
+                w_new = mu
+            else:
+                ky = jax.random.fold_in(key, y)
+                if rng != "host":
+                    ky = jax.random.fold_in(ky, chain0)
+                w_new = stats.draw_weight(ky, L, mu)
         W = W.at[y].set(w_new)
-        F = F.at[:, y].set(Xf @ w_new)
+        with jax.named_scope("score"):
+            F = F.at[:, y].set(Xf @ w_new)
         return (W, F)
 
     W_new, F = jax.lax.fori_loop(0, M, body, (W.astype(jnp.float32), F0))

@@ -26,6 +26,7 @@ from typing import Any, Callable, Sequence
 import jax
 import jax.numpy as jnp
 import numpy as np
+from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.checkpoint import Checkpointer
@@ -786,41 +787,47 @@ class PEMSVM:
         (epoch, step), and a superseded attempt's commits are rejected
         at the rename boundary (DESIGN.md §Reliability).
         """
-        rt = _FitRuntime(self, resume_from, resume_step, warm_start,
-                         live, fault_hook, epoch)
         cfg = self.config
-        X = np.asarray(X, np.float32)
-        y = np.asarray(y)
-        self._n_features = X.shape[1]
-        if cfg.add_bias and cfg.formulation == "LIN":
-            X = np.concatenate([X, np.ones((X.shape[0], 1), np.float32)], 1)
-        if cfg.pad_features:
-            # Explicit zero-column padding of the (post-bias) statistic
-            # width — the supported route to a k_shard-divisible K
-            # (padded columns carry zero statistics; the ridge pins
-            # their weights to 0, so predictions are unchanged).
-            from repro.data.pipeline import pad_features_to
-            X = pad_features_to(X, cfg.pad_features)
-        N = X.shape[0]
+        with TraceAnnotation("pemsvm.fit", driver=cfg.driver) as fit_span:
+            rt = _FitRuntime(self, resume_from, resume_step, warm_start,
+                             live, fault_hook, epoch)
+            with TraceAnnotation("pemsvm.bias") as span:
+                X = np.asarray(X, np.float32)
+                y = np.asarray(y)
+                fit_span.set_metadata(rows=X.shape[0], width=X.shape[1])
+                self._n_features = X.shape[1]
+                if cfg.add_bias and cfg.formulation == "LIN":
+                    X = np.concatenate(
+                        [X, np.ones((X.shape[0], 1), np.float32)], 1)
+                if cfg.pad_features:
+                    # Explicit zero-column padding of the (post-bias)
+                    # statistic width — the supported route to a
+                    # k_shard-divisible K (padded columns carry zero
+                    # statistics; the ridge pins their weights to 0, so
+                    # predictions are unchanged).
+                    from repro.data.pipeline import pad_features_to
+                    X = pad_features_to(X, cfg.pad_features)
+                span.set_metadata(bytes=X.nbytes)
+            N = X.shape[0]
 
-        try:
-            if cfg.driver == "stream":
-                if cfg.formulation == "KRN":
-                    raise NotImplementedError(
-                        "driver='stream' cannot use the exact N x N Gram "
-                        "statistic (not row-chunk-additive); use "
-                        "NystromSVM, whose phi-space route streams raw "
-                        "rows")
-                return self._fit_stream_arrays(X, y, rt)
+            try:
+                if cfg.driver == "stream":
+                    if cfg.formulation == "KRN":
+                        raise NotImplementedError(
+                            "driver='stream' cannot use the exact N x N "
+                            "Gram statistic (not row-chunk-additive); use "
+                            "NystromSVM, whose phi-space route streams raw "
+                            "rows")
+                    return self._fit_stream_arrays(X, y, rt)
 
-            data, prior, state = self._prepare(X, y)
-            if cfg.driver == "loop":
-                step = self._build_step(prior is not None,
-                                        self.mesh is not None)
-                return self._fit_loop(data, prior, state, step, N, rt)
-            return self._fit_scan(data, prior, state, N, rt)
-        finally:
-            rt.flush()
+                data, prior, state = self._prepare(X, y)
+                if cfg.driver == "loop":
+                    step = self._build_step(prior is not None,
+                                            self.mesh is not None)
+                    return self._fit_loop(data, prior, state, step, N, rt)
+                return self._fit_scan(data, prior, state, N, rt)
+            finally:
+                rt.flush()
 
     def fit_libsvm(self, path: str, n_features: int, rank: int = 0,
                    world: int = 1, **fit_kw) -> FitResult:
@@ -993,61 +1000,65 @@ class PEMSVM:
         while it0 < cfg.max_iters:
             t0 = time.perf_counter()
             chunk = min(cfg.scan_chunk, cfg.max_iters - it0)
-            its = jnp.arange(it0 + 1, it0 + chunk + 1, dtype=jnp.int32)
-            carry, aux_stack = runner(data, prior, carry, its, tol_n,
-                                      rt.live_dev)
-            # The single per-chunk host sync: flags, the chunk's sample
-            # sum, and the stacked aux trace in one transfer.
-            aux_np, chunk_sum, done_np, it_done_np = jax.device_get(
-                (aux_stack, carry[1], carry[6], carry[7]))
-            converged = bool(done_np)
-            it_done = int(it_done_np)
-            n_syncs += 1
-            samp_sum += np.asarray(chunk_sum, np.float64)
-            carry = (carry[0], jnp.zeros_like(carry[1])) + carry[2:]
-            valid = (it_done - it0) if converged else chunk
-            objs.extend(float(v) for v in aux_np["objective"][:valid])
-            for k, v in aux_np.items():
-                aux_hist.setdefault(k, []).extend(
-                    float(x) for x in v[:valid])
-            it0 += chunk
-            done_its = it_done if converged else it0
-            # Mirror the carry scalars into rt so snapshots see the same
-            # loop state the host-loop drivers would.
-            rt.key = carry[3]
-            rt.n_avg = int(carry[2])
-            rt.n_small = int(carry[5])
-            rt.cur_it = done_its
-            if rt.n_avg > 0:
-                rt.mean_w = samp_sum / rt.n_avg
-            if not converged and rt.boundary_due(done_its):
-                rt.save_snapshot(done_its, carry[0], samp_sum=samp_sum,
-                                 n_syncs=n_syncs)
-            if rt.hook is not None:
-                rt.hook(done_its)
-            rt.observe(done_its, time.perf_counter() - t0)
+            with TraceAnnotation("pemsvm.chunk", it0=it0, iters=chunk):
+                its = jnp.arange(it0 + 1, it0 + chunk + 1, dtype=jnp.int32)
+                with TraceAnnotation("pemsvm.dispatch"):
+                    carry, aux_stack = runner(data, prior, carry, its,
+                                              tol_n, rt.live_dev)
+                # The single per-chunk host sync: flags, the chunk's
+                # sample sum, and the stacked aux trace in one transfer.
+                with TraceAnnotation("pemsvm.sync"):
+                    aux_np, chunk_sum, done_np, it_done_np = jax.device_get(
+                        (aux_stack, carry[1], carry[6], carry[7]))
+                converged = bool(done_np)
+                it_done = int(it_done_np)
+                n_syncs += 1
+                samp_sum += np.asarray(chunk_sum, np.float64)
+                carry = (carry[0], jnp.zeros_like(carry[1])) + carry[2:]
+                valid = (it_done - it0) if converged else chunk
+                objs.extend(float(v) for v in aux_np["objective"][:valid])
+                for k, v in aux_np.items():
+                    aux_hist.setdefault(k, []).extend(
+                        float(x) for x in v[:valid])
+                it0 += chunk
+                done_its = it_done if converged else it0
+                # Mirror the carry scalars into rt so snapshots see the
+                # same loop state the host-loop drivers would.
+                rt.key = carry[3]
+                rt.n_avg = int(carry[2])
+                rt.n_small = int(carry[5])
+                rt.cur_it = done_its
+                if rt.n_avg > 0:
+                    rt.mean_w = samp_sum / rt.n_avg
+                if not converged and rt.boundary_due(done_its):
+                    rt.save_snapshot(done_its, carry[0], samp_sum=samp_sum,
+                                     n_syncs=n_syncs)
+                if rt.hook is not None:
+                    rt.hook(done_its)
+                rt.observe(done_its, time.perf_counter() - t0)
             if converged:
                 break
 
-        n_iters = it_done if converged else it0
-        last = np.asarray(carry[0], np.float32)
-        n_avg = int(carry[2])
-        weights = ((samp_sum / n_avg).astype(np.float32)
-                   if n_avg > 0 else last)
-        self._weights = weights
-        if rt.ckpt is not None and n_iters > rt.last_saved_it:
-            rt.save_snapshot(n_iters, carry[0], converged=converged,
-                             samp_sum=samp_sum, n_syncs=n_syncs,
-                             blocking=True)
-        return self._finalize_chains(FitResult(
-                         weights=weights, last_sample=last, objective=objs,
-                         aux_history=aux_hist, n_iters=n_iters,
-                         converged=converged, n_host_syncs=n_syncs,
-                         straggler_events=rt.events,
-                         resumed_at=rt.resumed_at,
-                         n_checkpoints=rt.n_checkpoints,
-                         loader_retries=rt.retry_stats.retries,
-                         loader_backoff_s=rt.retry_stats.backoff_s))
+        with TraceAnnotation("pemsvm.finalize"):
+            n_iters = it_done if converged else it0
+            last = np.asarray(carry[0], np.float32)
+            n_avg = int(carry[2])
+            weights = ((samp_sum / n_avg).astype(np.float32)
+                       if n_avg > 0 else last)
+            self._weights = weights
+            if rt.ckpt is not None and n_iters > rt.last_saved_it:
+                rt.save_snapshot(n_iters, carry[0], converged=converged,
+                                 samp_sum=samp_sum, n_syncs=n_syncs,
+                                 blocking=True)
+            return self._finalize_chains(FitResult(
+                weights=weights, last_sample=last, objective=objs,
+                aux_history=aux_hist, n_iters=n_iters,
+                converged=converged, n_host_syncs=n_syncs,
+                straggler_events=rt.events,
+                resumed_at=rt.resumed_at,
+                n_checkpoints=rt.n_checkpoints,
+                loader_retries=rt.retry_stats.retries,
+                loader_backoff_s=rt.retry_stats.backoff_s))
 
     def _finalize_chains(self, result: FitResult) -> FitResult:
         """Multichain post-processing, shared by every driver: the raw
@@ -1384,14 +1395,16 @@ class PEMSVM:
     def _prepare(self, X: np.ndarray, y: np.ndarray):
         cfg = self.config
         N, K = X.shape
-        if cfg.task == "CLS":
-            target = np.asarray(y, np.float32)
-            uniq = set(np.unique(target).tolist())
-            assert uniq <= {-1.0, 1.0}, f"CLS labels must be +-1, got {uniq}"
-        elif cfg.task == "MLT":
-            target = np.asarray(y, np.int32)
-        else:
-            target = np.asarray(y, np.float32)
+        with TraceAnnotation("pemsvm.labels", rows=N):
+            if cfg.task == "CLS":
+                target = np.asarray(y, np.float32)
+                uniq = set(np.unique(target).tolist())
+                assert uniq <= {-1.0, 1.0}, (
+                    f"CLS labels must be +-1, got {uniq}")
+            elif cfg.task == "MLT":
+                target = np.asarray(y, np.int32)
+            else:
+                target = np.asarray(y, np.float32)
 
         if cfg.formulation == "KRN":
             if cfg.task != "CLS":
@@ -1430,25 +1443,30 @@ class PEMSVM:
                                           target)
         else:
             Xp, tp, mask = distributed.pad_rows(X, target, 1)
-            data = SVMData(jnp.asarray(Xp), jnp.asarray(tp),
-                           jnp.asarray(mask))
-        prior = None
-        if cfg.phi_spec is not None:
-            K = self._phi_width()
-            prior = tuple(jnp.asarray(a, jnp.float32)
-                          for a in self._phi_arrays)
+            with TraceAnnotation("pemsvm.upload",
+                                 bytes=Xp.nbytes + tp.nbytes + mask.nbytes):
+                data = SVMData(jnp.asarray(Xp), jnp.asarray(tp),
+                               jnp.asarray(mask))
+        with TraceAnnotation("pemsvm.upload") as span:
+            prior = None
+            if cfg.phi_spec is not None:
+                K = self._phi_width()
+                prior = tuple(jnp.asarray(a, jnp.float32)
+                              for a in self._phi_arrays)
+                if self.mesh is not None:
+                    rep = NamedSharding(self.mesh, P(None, None))
+                    prior = tuple(jax.device_put(a, rep) for a in prior)
+            if cfg.task == "MLT":
+                state = jnp.zeros((cfg.num_classes, K), jnp.float32)
+            elif cfg.n_chains > 1:
+                state = jnp.zeros((cfg.n_chains, K), jnp.float32)
+            else:
+                state = jnp.zeros((K,), jnp.float32)
             if self.mesh is not None:
-                rep = NamedSharding(self.mesh, P(None, None))
-                prior = tuple(jax.device_put(a, rep) for a in prior)
-        if cfg.task == "MLT":
-            state = jnp.zeros((cfg.num_classes, K), jnp.float32)
-        elif cfg.n_chains > 1:
-            state = jnp.zeros((cfg.n_chains, K), jnp.float32)
-        else:
-            state = jnp.zeros((K,), jnp.float32)
-        if self.mesh is not None:
-            state = jax.device_put(state, NamedSharding(
-                self.mesh, P(*(None,) * state.ndim)))
+                state = jax.device_put(state, NamedSharding(
+                    self.mesh, P(*(None,) * state.ndim)))
+            span.set_metadata(bytes=state.nbytes + sum(
+                a.nbytes for a in prior or ()))
         return data, prior, state
 
     def _build_step(self, has_prior: bool, has_live: bool = False):

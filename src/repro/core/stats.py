@@ -68,17 +68,18 @@ def preduce(x: jnp.ndarray, axes: Sequence[str] | None,
     unconditionally on the mesh path."""
     if not axes:
         return x
-    if live is None:
-        return jax.lax.psum(x, tuple(axes))
-    lv = jnp.reshape(live, ())
-    # Weight in x's dtype (liveness is 0/1 — exact even in bf16) so a
-    # reduce_dtype-compressed payload stays compressed on the wire; the
-    # den psum is one fp32 scalar.
-    num = jax.lax.psum(lv.astype(x.dtype) * x, tuple(axes))
-    den = jax.lax.psum(lv.astype(jnp.float32), tuple(axes))
-    total = float(np.prod([jax.lax.axis_size(a) for a in axes]))
-    scale = total / jnp.maximum(den, 1.0)
-    return num * scale.astype(num.dtype)
+    with jax.named_scope("psum"):
+        if live is None:
+            return jax.lax.psum(x, tuple(axes))
+        lv = jnp.reshape(live, ())
+        # Weight in x's dtype (liveness is 0/1 — exact even in bf16) so a
+        # reduce_dtype-compressed payload stays compressed on the wire;
+        # the den psum is one fp32 scalar.
+        num = jax.lax.psum(lv.astype(x.dtype) * x, tuple(axes))
+        den = jax.lax.psum(lv.astype(jnp.float32), tuple(axes))
+        total = float(np.prod([jax.lax.axis_size(a) for a in axes]))
+        scale = total / jnp.maximum(den, 1.0)
+        return num * scale.astype(num.dtype)
 
 
 def masked_mean(x: jnp.ndarray, mask: jnp.ndarray,
@@ -132,22 +133,24 @@ def reduce_stats(S: jnp.ndarray, b: jnp.ndarray,
     def uncast(x):
         return x.astype(jnp.float32) if reduce_dtype else x
 
-    if not triangle:
-        return (uncast(preduce(maybe_cast(S), axes, live)),
-                uncast(preduce(maybe_cast(b), axes, live)))
-    if S.ndim == 3:
-        C, K = S.shape[0], S.shape[1]
-        tri = K * (K + 1) // 2
-        fused = jnp.concatenate([jax.vmap(triangle_pack)(S).reshape(-1),
-                                 b.reshape(-1)])
+    with jax.named_scope("psum"):
+        if not triangle:
+            return (uncast(preduce(maybe_cast(S), axes, live)),
+                    uncast(preduce(maybe_cast(b), axes, live)))
+        if S.ndim == 3:
+            C, K = S.shape[0], S.shape[1]
+            tri = K * (K + 1) // 2
+            fused = jnp.concatenate(
+                [jax.vmap(triangle_pack)(S).reshape(-1), b.reshape(-1)])
+            fused = uncast(preduce(maybe_cast(fused), axes, live))
+            S = jax.vmap(lambda p: triangle_unpack(p, K))(
+                fused[: C * tri].reshape(C, tri))
+            return S, fused[C * tri:].reshape(b.shape)
+        K = S.shape[0]
+        fused = jnp.concatenate([triangle_pack(S), b])
         fused = uncast(preduce(maybe_cast(fused), axes, live))
-        S = jax.vmap(lambda p: triangle_unpack(p, K))(
-            fused[: C * tri].reshape(C, tri))
-        return S, fused[C * tri:].reshape(b.shape)
-    K = S.shape[0]
-    fused = jnp.concatenate([triangle_pack(S), b])
-    fused = uncast(preduce(maybe_cast(fused), axes, live))
-    return triangle_unpack(fused[: K * (K + 1) // 2], K), fused[K * (K + 1) // 2:]
+        tri = K * (K + 1) // 2
+        return triangle_unpack(fused[:tri], K), fused[tri:]
 
 
 def reduce_kshard(S_blk: jnp.ndarray, b: jnp.ndarray,

@@ -216,13 +216,14 @@ def fused_stats(X: jnp.ndarray, rho: jnp.ndarray, beta: jnp.ndarray,
     Kp = _round_up(K, 128)
     Np = _round_up(N, bn)
     if (Np, Kp) != (N, K):
-        X = jnp.pad(X, ((0, Np - N), (0, Kp - K)))
-        rho = jnp.pad(rho, (0, Np - N))
-        beta = jnp.pad(beta, (0, Np - N))
-        wmask = jnp.pad(wmask, (0, Np - N))
-        wvec = (jnp.pad(wvec, ((0, Kp - K), (0, 0))) if multi
-                else jnp.pad(wvec, (0, Kp - K)))
-        noise = tuple(jnp.pad(z, (0, Np - N)) for z in noise)
+        with jax.named_scope("xpad"):
+            X = jnp.pad(X, ((0, Np - N), (0, Kp - K)))
+            rho = jnp.pad(rho, (0, Np - N))
+            beta = jnp.pad(beta, (0, Np - N))
+            wmask = jnp.pad(wmask, (0, Np - N))
+            wvec = (jnp.pad(wvec, ((0, Kp - K), (0, 0))) if multi
+                    else jnp.pad(wvec, (0, Kp - K)))
+            noise = tuple(jnp.pad(z, (0, Np - N)) for z in noise)
 
     extra_specs: list = []
     extra_ops: tuple = ()
@@ -264,6 +265,7 @@ def fused_stats(X: jnp.ndarray, rho: jnp.ndarray, beta: jnp.ndarray,
             jax.ShapeDtypeStruct((Kp, C * Sw), jnp.float32),
         ],
         interpret=interpret,
+        name="fused_stats",
     )(*extra_ops, X, rho.reshape(Np, 1), beta.reshape(Np, 1),
       wmask.reshape(Np, 1),
       wvec.reshape(Kp, C),
