@@ -98,9 +98,7 @@ def time_step(svm, X, y) -> dict:
     from repro.core import solver
 
     cfg = svm.config
-    if cfg.add_bias and cfg.formulation == "LIN":
-        X = with_bias(X)
-    data, prior, state = svm._prepare(X, y)
+    data, prior, state = svm._prepare(np.asarray(X, np.float32), y)
     step = solver._build_step_fn(cfg, None, (), prior is not None)
     args = (data, prior) if prior is not None else (data,)
     t0 = time.perf_counter()

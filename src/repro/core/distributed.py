@@ -4,9 +4,12 @@ Every step function in this package is written over a *local* shard with
 explicit ``psum`` reductions over ``axes``; this module supplies the
 machinery around them:
 
-  * ``shard_rows`` — partition the training set across the mesh's data
-    axes exactly like the paper assigns D^p to process p (padding rows are
-    zeroed and masked so statistics are exact).
+  * ``upload_rows`` — place the caller's rows on the device(s) once and
+    build the model's X there (bias column, zero feature columns, zero
+    pad rows), row-sharded over the mesh's data axes exactly like the
+    paper assigns D^p to process p (padding rows are zeroed and masked
+    so statistics are exact). ``shard_rows`` places a matrix the host
+    has already built (the exact-KRN Gram).
   * ``shard_wrap`` — wrap a step function in ``shard_map`` so each device
     runs the identical SPMD program (the paper's observation that all
     slaves perform the same operations — hence minimal sync latency — is
@@ -50,24 +53,34 @@ def num_shards(mesh: Mesh, axes: Sequence[str]) -> int:
 
 def pad_rows(X: np.ndarray, target: np.ndarray, shards: int,
              multiple: int = 8):
-    """Zero-pad rows to a multiple of (shards * multiple); returns SVMData
-    host arrays. Padded rows: X-row = 0, target = 0, mask = 0."""
+    """Zero-pad rows to a multiple of (shards * multiple) in host copies;
+    returns SVMData host arrays. Padded rows: X-row = 0, target = 0,
+    mask = 0.
+
+    Used where the host builds the arrays it uploads: the stream driver's
+    chunks and, through ``shard_rows``, the exact-KRN Gram. The resident
+    LIN path pads on the device instead (``upload_rows``)."""
     N = X.shape[0]
     chunk = shards * multiple
     Np = ((N + chunk - 1) // chunk) * chunk
     pad = Np - N
-    with TraceAnnotation("pemsvm.pad_rows", rows=N, pad_rows=pad):
+    with TraceAnnotation("pemsvm.pad_rows", rows=N, pad_rows=pad) as span:
         Xp = np.concatenate([X, np.zeros((pad,) + X.shape[1:], X.dtype)],
                             axis=0)
         tp = np.concatenate([target, np.zeros((pad,), target.dtype)], axis=0)
         mask = np.concatenate([np.ones((N,), np.float32),
                                np.zeros((pad,), np.float32)], axis=0)
+        span.set_metadata(host_bytes=Xp.nbytes)
     return Xp, tp, mask
 
 
 def shard_rows(mesh: Mesh, axes: Sequence[str], X: np.ndarray,
                target: np.ndarray) -> SVMData:
-    """Place the training set row-sharded over ``axes`` (paper Sec 4.1).
+    """Place a host-built training matrix row-sharded over ``axes``
+    (paper Sec 4.1), padded by ``pad_rows``.
+
+    The exact-KRN path places its Gram through here; the resident LIN
+    path uploads raw rows with ``upload_rows``.
 
     I/O note (paper Sec 5.6): in a real multi-host deployment each host
     feeds only its addressable shard (repro.data.pipeline); here the
@@ -83,6 +96,86 @@ def shard_rows(mesh: Mesh, axes: Sequence[str], X: np.ndarray,
             target=jax.device_put(tp, NamedSharding(mesh, row_spec)),
             mask=jax.device_put(mask, NamedSharding(mesh, row_spec)),
         )
+
+
+@functools.lru_cache(maxsize=None)
+def _augment_program(pad: int, bias: bool, fpad: int, sharding=None):
+    """Jitted (X, mask) -> X with ``pad`` zero rows, then the bias column
+    and ``fpad`` zero columns, appended. The bias column is the mask: 1
+    on real rows, 0 on pad rows. Row-local, so a row-sharded X is
+    augmented block by block with no communication."""
+    def augment(X, mask):
+        with jax.named_scope("augment"):
+            X = jnp.pad(X, ((0, pad), (0, 0)))
+            if bias:
+                X = jnp.concatenate([X, mask[:, None]], axis=1)
+            return jnp.pad(X, ((0, 0), (0, fpad)))
+    return jax.jit(augment, out_shardings=sharding)
+
+
+def upload_rows(X: np.ndarray, target: np.ndarray, mesh: Mesh | None,
+                axes: Sequence[str], *, bias: bool,
+                fpad: int = 0) -> SVMData:
+    """Send the caller's (N, D) rows to the device(s) once and build the
+    model's X there: the bias column (``bias``), ``fpad`` zero feature
+    columns, and zero rows up to a multiple of (shards * 8), as
+    ``pad_rows`` pads.
+
+    Bitwise the array ``pad_rows`` + ``shard_rows`` build from a host
+    copy with the columns appended, in the same row order, but the host
+    copies no row of a float32 X: one device pads its rows on the
+    device; on a mesh each device's block is a view of X, and only a
+    block that reaches past row N is copied into a zero block. The
+    ``pemsvm.pad_rows`` span's ``host_bytes`` counts those copies.
+    """
+    N, D = X.shape
+    shards = num_shards(mesh, axes) if mesh is not None else 1
+    chunk = shards * 8
+    Np = ((N + chunk - 1) // chunk) * chunk
+    pad = Np - N
+    with TraceAnnotation("pemsvm.pad_rows", rows=N, pad_rows=pad) as span:
+        tp = np.concatenate([target, np.zeros((pad,), target.dtype)])
+        mask = np.concatenate([np.ones((N,), np.float32),
+                               np.zeros((pad,), np.float32)])
+        copied = 0
+        if mesh is not None:
+            sharding = NamedSharding(mesh, P(tuple(axes), None))
+            blocks, by_rows = {}, {}
+            for dev, idx in sharding.addressable_devices_indices_map(
+                    (Np, D)).items():
+                a, b, _ = idx[0].indices(Np)
+                if (a, b) not in by_rows:
+                    if b <= N:
+                        by_rows[a, b] = X[a:b]
+                    else:
+                        blk = np.zeros((b - a, D), X.dtype)
+                        blk[:max(N - a, 0)] = X[a:N]
+                        by_rows[a, b] = blk
+                        copied += blk.nbytes
+                blocks[dev] = by_rows[a, b]
+        span.set_metadata(host_bytes=copied)
+    with TraceAnnotation("pemsvm.upload") as span:
+        if mesh is None:
+            raw = jnp.asarray(X)
+            tp_d, mask_d = jnp.asarray(tp), jnp.asarray(mask)
+            dev_pad, out_sharding, up = pad, None, X.nbytes
+        else:
+            devs = list(blocks)
+            raw = jax.make_array_from_single_device_arrays(
+                (Np, D), sharding,
+                jax.device_put([blocks[d] for d in devs], devs))
+            row_sharding = NamedSharding(mesh, P(tuple(axes)))
+            tp_d = jax.device_put(tp, row_sharding)
+            mask_d = jax.device_put(mask, row_sharding)
+            dev_pad, out_sharding = 0, sharding
+            up = sum(blocks[d].nbytes for d in devs)
+        span.set_metadata(bytes=up + tp.nbytes + mask.nbytes)
+        if not (dev_pad or bias or fpad):
+            return SVMData(raw, tp_d, mask_d)
+        # The raw rows go with this frame: their device memory is freed
+        # once the program has read them.
+        program = _augment_program(dev_pad, bias, fpad, out_sharding)
+        return SVMData(program(raw, mask_d), tp_d, mask_d)
 
 
 def shard_wrap(mesh: Mesh, axes: Sequence[str],

@@ -792,22 +792,20 @@ class PEMSVM:
             rt = _FitRuntime(self, resume_from, resume_step, warm_start,
                              live, fault_hook, epoch)
             with TraceAnnotation("pemsvm.bias") as span:
-                X = np.asarray(X, np.float32)
+                Xf = np.asarray(X, np.float32)
+                copied = (0 if isinstance(X, np.ndarray)
+                          and np.may_share_memory(Xf, X) else Xf.nbytes)
+                X = Xf
                 y = np.asarray(y)
                 fit_span.set_metadata(rows=X.shape[0], width=X.shape[1])
                 self._n_features = X.shape[1]
-                if cfg.add_bias and cfg.formulation == "LIN":
-                    X = np.concatenate(
-                        [X, np.ones((X.shape[0], 1), np.float32)], 1)
-                if cfg.pad_features:
-                    # Explicit zero-column padding of the (post-bias)
-                    # statistic width — the supported route to a
-                    # k_shard-divisible K (padded columns carry zero
-                    # statistics; the ridge pins their weights to 0, so
-                    # predictions are unchanged).
-                    from repro.data.pipeline import pad_features_to
-                    X = pad_features_to(X, cfg.pad_features)
-                span.set_metadata(bytes=X.nbytes)
+                if cfg.driver == "stream":
+                    # The stream driver uploads host chunks one at a
+                    # time, so their columns are built here; the resident
+                    # drivers build them on the device (``_prepare``).
+                    X, more = self._host_columns(X)
+                    copied += more
+                span.set_metadata(bytes=X.nbytes, host_bytes=copied)
             N = X.shape[0]
 
             try:
@@ -908,6 +906,25 @@ class PEMSVM:
             return self._fit_stream(make_chunks, K, rt)
         finally:
             rt.flush()
+
+    def _host_columns(self, X: np.ndarray) -> tuple[np.ndarray, int]:
+        """X with the LIN bias column and any ``pad_features`` zero
+        columns appended in host copies, and the bytes those copies
+        wrote. Zero-column padding of the (post-bias) statistic width is
+        the supported route to a k_shard-divisible K (padded columns
+        carry zero statistics; the ridge pins their weights to 0, so
+        predictions are unchanged)."""
+        cfg = self.config
+        copied = 0
+        if cfg.add_bias and cfg.formulation == "LIN":
+            X = np.concatenate([X, np.ones((X.shape[0], 1), np.float32)], 1)
+            copied += X.nbytes
+        if cfg.pad_features:
+            from repro.data.pipeline import pad_features_to
+            Xq = pad_features_to(X, cfg.pad_features)
+            copied += 0 if Xq is X else Xq.nbytes
+            X = Xq
+        return X, copied
 
     def _stream_target(self, y: np.ndarray, mask: np.ndarray) -> np.ndarray:
         """Validate + cast one chunk's labels (the _prepare checks,
@@ -1393,6 +1410,8 @@ class PEMSVM:
 
     # ------------------------------------------------------ setup helpers
     def _prepare(self, X: np.ndarray, y: np.ndarray):
+        """(data, prior, state) on the device(s) from the caller's
+        float32 rows: the model's columns and pad rows are built there."""
         cfg = self.config
         N, K = X.shape
         with TraceAnnotation("pemsvm.labels", rows=N):
@@ -1436,17 +1455,15 @@ class PEMSVM:
             state = jnp.zeros((Gp.shape[0],), jnp.float32)
             return data, prior, state
 
-        # LIN (raw rows in phi-space mode: featurization happens inside
-        # the step, so only D-wide rows are sharded/resident)
-        if self.mesh is not None:
-            data = distributed.shard_rows(self.mesh, self.data_axes, X,
-                                          target)
-        else:
-            Xp, tp, mask = distributed.pad_rows(X, target, 1)
-            with TraceAnnotation("pemsvm.upload",
-                                 bytes=Xp.nbytes + tp.nbytes + mask.nbytes):
-                data = SVMData(jnp.asarray(Xp), jnp.asarray(tp),
-                               jnp.asarray(mask))
+        # LIN: the caller's rows go to the device once and the bias and
+        # zero columns and rows are appended there (raw rows in
+        # phi-space mode: featurization happens inside the step, so only
+        # D-wide rows are sharded/resident).
+        fpad = ((-(K + cfg.add_bias)) % cfg.pad_features
+                if cfg.pad_features else 0)
+        data = distributed.upload_rows(X, target, self.mesh, self.data_axes,
+                                       bias=cfg.add_bias, fpad=fpad)
+        K = data.X.shape[1]
         with TraceAnnotation("pemsvm.upload") as span:
             prior = None
             if cfg.phi_spec is not None:
@@ -1577,13 +1594,7 @@ class PEMSVM:
                 kind=cfg.phi_spec.kind, add_bias=cfg.phi_spec.add_bias,
                 backend=cfg.backend)
         else:
-            if cfg.add_bias:
-                X = np.concatenate(
-                    [X, np.ones((X.shape[0], 1), np.float32)], 1)
-            if cfg.pad_features:
-                from repro.data.pipeline import pad_features_to
-                X = pad_features_to(X, cfg.pad_features)
-            Xp = jnp.asarray(X)
+            Xp = jnp.asarray(self._host_columns(X)[0])
         yf = jnp.asarray(np.asarray(y, np.float32))
         beta = yf if cfg.task == "CLS" else jnp.zeros_like(yf)
         epi = "em_hinge" if cfg.task == "CLS" else "em_svr"
