@@ -4,8 +4,8 @@
     python3 bench/control.py --workload <cell> --seeds 11 12 13 [--control] [--look]
 
 For each seed: the cell's data made on the device, one fit through the
-program's entry point at the cell's own size, then the reference from
-scratch. Prints one JSON line per seed with the numbers that
+estimator the configuration names at the cell's own size, then the
+reference from scratch. Prints one JSON line per seed with the numbers that
 ``correct`` compares (``harness.compare``), the objective gap at every
 iteration and the relative gap of each class's weights. With
 ``--control`` the same numbers are read for the control (the reference
@@ -45,15 +45,14 @@ def class_gaps(w, ref_w) -> list:
 
 
 def program_fit(job, mesh):
-    """(weights, objective trace) of one fit through ``PEMSVM.fit``."""
+    """(weights, objective trace) of one fit through the estimator's
+    ``fit``."""
     import numpy as np
-
-    from repro.core import PEMSVM
 
     X, t = harness.make_data(job, mesh)
     X_host, t_host = np.asarray(X), np.asarray(t)
     del X, t
-    res = PEMSVM(harness.svm_config(job), mesh=mesh).fit(X_host, t_host)
+    res = harness.estimator(job, mesh).fit(X_host, t_host)
     return np.asarray(res.weights), list(res.objective)
 
 

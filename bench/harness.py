@@ -1,11 +1,11 @@
 """The benchmark's machinery, driven by ``BENCHMARK.json`` and the files
 it names: a cell's configuration (``bench/configs/<config>.json``), its
 traffic (``bench/traffic/<traffic>.json``), its correctness limits
-(``bench/checks/<workload>.json``), the data generator and reference the
-configuration names (``bench/data/<dataset>.py``,
-``bench/reference/<reference>.py``) and one reader per per-layer metric
-(``bench/metrics/<metric>.py``). Adding a cell, a mix or a metric adds
-files and entries; nothing here names one.
+(``bench/checks/<workload>.json``), the estimator, data generator and
+reference the configuration names (``estimator``, default ``PEMSVM``;
+``bench/data/<dataset>.py``, ``bench/reference/<reference>.py``) and one
+reader per per-layer metric (``bench/metrics/<metric>.py``). Adding a
+cell, a mix or a metric adds files and entries; nothing here names one.
 """
 from __future__ import annotations
 
@@ -16,11 +16,15 @@ import importlib.util
 import json
 import math
 import time
+from collections.abc import Callable
 from pathlib import Path
+
+from bench import work
 
 ROOT = Path(__file__).resolve().parents[1]
 CACHE_DIR = ROOT / ".jax_cache"
 TRACE_DIR = ROOT / ".bench_trace"
+DEFAULT_ESTIMATOR = "PEMSVM"
 
 
 def read_json(path: Path) -> dict:
@@ -63,9 +67,20 @@ class Job:
         return self.traffic["iters"]
 
     @property
+    def estimator(self) -> str:
+        """The program's estimator class that fits the configuration."""
+        return self.config.get("estimator", DEFAULT_ESTIMATOR)
+
+    @property
+    def spec(self) -> "Estimator":
+        """What the harness knows of that estimator."""
+        return ESTIMATORS[self.estimator]
+
+    @property
     def width(self) -> int:
-        """Statistic width K: features plus the bias column."""
-        return self.config["n_features"] + int(self.config["add_bias"])
+        """Width K of the statistic the estimator accumulates: the
+        configuration's ``spec.width_key`` plus the bias column."""
+        return self.config[self.spec.width_key] + int(self.config["add_bias"])
 
     @property
     def classes(self) -> int:
@@ -96,6 +111,11 @@ def resolve(name: str, seed: int, root: Path = ROOT, **traffic_kw) -> Job:
         raise ValueError(f"{name}: traffic mesh {traffic['mesh']} does not "
                          f"span the cell's {cell['chips']} chip(s)")
     n = traffic["rows_per_chip"] * cell["chips"]
+    est = config.get("estimator", DEFAULT_ESTIMATOR)
+    if est not in ESTIMATORS:
+        raise ValueError(f"{centry['file']}: estimator {est!r} is not one "
+                         f"of {sorted(ESTIMATORS)}")
+    ESTIMATORS[est].check(config, centry["file"], n)
     # lam = 2 / C (paper Eq. 1), scaled with the rows so the prior
     # weighs the data as it does at the source's size.
     config = dict(config, lam=2.0 / config["C"] * n / config["source_rows"])
@@ -107,6 +127,68 @@ def resolve(name: str, seed: int, root: Path = ROOT, **traffic_kw) -> Job:
     return Job(name, cell["chips"], config, traffic, checks,
                tuple(m for m in spec["end_to_end"] if applies(m)),
                tuple(m for m in spec["per_layer"] if applies(m)), seed)
+
+
+@dataclasses.dataclass(frozen=True)
+class Estimator:
+    """What the harness knows of one of the program's estimators. Every
+    choice that differs by estimator reads its entry in ``ESTIMATORS``;
+    another estimator adds an entry, and its counts to ``work.py``."""
+    width_key: str          # config key of the statistic's width, bias aside
+    x_bias: bool            # X itself carries the bias column (add_bias)
+    kernel: str             # HLO name of its fused statistic kernel
+    pass_flops: Callable    # (n, job) -> FLOPs of one class pass
+    call_counts: Callable   # (n, job) -> (FLOPs, bytes) of one kernel call
+    check: Callable         # (config, file, rows) -> None, or raises
+    build: Callable         # (SVMConfig, job, mesh) -> the estimator
+
+
+def _no_check(config: dict, file: str, rows: int) -> None:
+    pass
+
+
+def _check_nystrom(config: dict, file: str, rows: int) -> None:
+    """Refuse a NystromSVM configuration the program cannot fit, naming
+    the key at fault."""
+    m = config.get("n_landmarks")
+    if type(m) is not int or not 1 <= m <= rows:
+        raise ValueError(f"{file}: n_landmarks {m!r}: NystromSVM needs a "
+                         f"whole number of landmarks from 1 to the {rows} "
+                         f"rows")
+    if not config["options"].startswith("KRN-"):
+        raise ValueError(f"{file}: options {config['options']!r}: "
+                         f"NystromSVM fits a KRN configuration")
+    if not config["add_bias"]:
+        raise ValueError(f"{file}: add_bias false: NystromSVM always "
+                         f"carries its bias in phi-space")
+
+
+def _build_pemsvm(cfg, job: Job, mesh):
+    from repro.core import PEMSVM
+
+    return PEMSVM(cfg, mesh=mesh)
+
+
+def _build_nystrom(cfg, job: Job, mesh):
+    """NystromSVM draws its landmarks from the run's seed (``fit_seed``),
+    so a reference given that seed can draw the same ones."""
+    from repro.core import NystromSVM
+
+    return NystromSVM(cfg, n_landmarks=job.config["n_landmarks"],
+                      mesh=mesh, seed=job.fit_seed)
+
+
+# The program's estimators a configuration may name, by class name.
+# NystromSVM's statistic is phi-space (width n_landmarks + bias, the bias
+# in phi), so its X and its reference's rows carry no bias column.
+ESTIMATORS = {
+    "PEMSVM": Estimator("n_features", True, "fused_stats",
+                        work.lin_pass_flops, work.lin_call, _no_check,
+                        _build_pemsvm),
+    "NystromSVM": Estimator("n_landmarks", False, "nystrom_fused_stats",
+                            work.nystrom_pass_flops, work.nystrom_call,
+                            _check_nystrom, _build_nystrom),
+}
 
 
 def load_metric(name: str, root: Path = ROOT):
@@ -179,31 +261,44 @@ def make_data(job: Job, mesh):
 
 
 def svm_config(job: Job):
-    """The program's SVMConfig from its own paper factory, with the
-    changes the configuration file lists; every other value the file
-    states is checked against what the factory gives."""
+    """The program's SVMConfig from its own paper factory. Each key of the
+    configuration file that is a field of SVMConfig is applied from the
+    file where its ``reduced`` lists the key, and otherwise checked
+    against what the factory gives, as are ``options`` and, unless
+    ``reduced`` lists it, ``C`` (lam = 2 / C); a mismatch raises."""
     from repro.configs import svm_paper
+    from repro.core import SVMConfig
 
-    base = getattr(svm_paper, job.config["factory"])()
     c = job.config
+    reduced = c["reduced"]
+    base = getattr(svm_paper, c["factory"])()
     a = c["options"].split("-")[1]
-    if a != base.algorithm and "algorithm" in c["reduced"]:
+    if a != base.algorithm and "algorithm" in reduced:
         base = dataclasses.replace(base, algorithm=a)
-    stated = {"options": base.options, "lam": base.lam,
-              "num_classes": base.num_classes, "eps": base.eps,
-              "jitter": base.jitter, "add_bias": base.add_bias}
-    want = {"options": c["options"], "lam": 2.0 / c["C"],
-            "num_classes": c["num_classes"], "eps": c["eps"],
-            "jitter": c["jitter"], "add_bias": c["add_bias"]}
-    for k, v in want.items():
-        ok = (math.isclose(stated[k], v, rel_tol=1e-12)
-              if isinstance(v, float) else stated[k] == v)
+    fields = {f.name for f in dataclasses.fields(SVMConfig)}
+    stated = {k: v for k, v in c.items() if k in fields}
+    applied = {k: v for k, v in stated.items() if k in reduced}
+    checked = {k: (getattr(base, k), v) for k, v in stated.items()
+               if k not in reduced}
+    checked["options"] = (base.options, c["options"])
+    if "C" not in reduced:
+        checked["lam (2 / C)"] = (base.lam, 2.0 / c["C"])
+    for k, (got, want) in checked.items():
+        ok = (math.isclose(got, want, rel_tol=1e-12)
+              if isinstance(want, float) and isinstance(got, (int, float))
+              else got == want)
         if not ok:
-            raise ValueError(f"{job.config['factory']}: {k} is {stated[k]!r}"
-                             f", the configuration file states {v!r}")
-    return dataclasses.replace(base, lam=c["lam"], tol=c["tol"],
-                               min_iters=job.iters, max_iters=job.iters,
-                               seed=job.fit_seed)
+            raise ValueError(f"{c['factory']}: {k} is {got!r}, the "
+                             f"configuration file states {want!r}")
+    return dataclasses.replace(base, **dict(
+        applied, min_iters=job.iters, max_iters=job.iters,
+        seed=job.fit_seed))
+
+
+def estimator(job: Job, mesh):
+    """The estimator the configuration names, built from ``svm_config``
+    as a user builds it."""
+    return job.spec.build(svm_config(job), job, mesh)
 
 
 def digest(weights) -> str:
@@ -248,8 +343,10 @@ def run_window(fit, seconds: float, span=None) -> Window:
 
 
 def layout(job: Job, X, t, mesh):
-    """The reference's (S, n, K) rows with the bias column, on the
-    cell's devices; takes over X's buffer."""
+    """The reference's (S, n, K) rows on the cell's devices, with the
+    bias column where the estimator's X carries one (``spec.x_bias`` and
+    ``add_bias``); NystromSVM's reference gets the raw rows and
+    featurises them itself. Takes over X's buffer."""
     import jax
     import jax.numpy as jnp
     from jax.sharding import NamedSharding, PartitionSpec as P
@@ -257,8 +354,10 @@ def layout(job: Job, X, t, mesh):
     S = job.chips
     N, D = X.shape
 
+    bias = job.spec.x_bias and job.config["add_bias"]
+
     def f(X, t):
-        if job.config["add_bias"]:
+        if bias:
             X = jnp.concatenate([X, jnp.ones((N, 1), X.dtype)], axis=1)
         return X.reshape(S, N // S, X.shape[1]), t.reshape(S, N // S)
 

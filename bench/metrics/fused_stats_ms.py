@@ -1,21 +1,27 @@
 """fused_stats_ms: device time of the fused statistic kernel per
 iteration (M calls per MLT sweep), on the slowest device.
 
-The kernel is ``kernels/fused_stats.py``'s ``pallas_call``, which the
-compiled HLO names after its jitted wrapper: ``%fused_stats.<n> = ...
-custom-call(...)``.
+The kernel is the one the configuration's estimator runs
+(``harness.ESTIMATORS``, ``kernel``): ``kernels/fused_stats.py``'s
+``pallas_call`` for PEMSVM, ``kernels/nystrom_phi.py``'s
+``nystrom_fused_stats`` for NystromSVM, which takes its place on that
+path. The compiled HLO names each after its jitted wrapper:
+``%fused_stats.<n> = ... custom-call(...)``.
 """
 import re
 
-KERNEL = re.compile(r"^fused_stats(\.\d+)?$")
+
+def kernel_pattern(job):
+    return re.compile(rf"^{re.escape(job.spec.kernel)}(\.\d+)?$")
 
 
 def kernel_events(ctx, device):
     from bench.tracefile import op_name
 
     lo, hi = ctx.trace.window
+    kernel = kernel_pattern(ctx.job)
     return [e for e in ctx.trace.ops.get(device, [])
-            if e.start >= lo and e.end <= hi and KERNEL.match(op_name(e.name))]
+            if e.start >= lo and e.end <= hi and kernel.match(op_name(e.name))]
 
 
 def per_device(ctx):

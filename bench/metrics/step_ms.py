@@ -19,10 +19,11 @@ def program_intervals(ctx, device):
 
 
 def read(ctx):
-    from bench.tracefile import busy_seconds
+    from bench.tracefile import busy_in
 
-    per_dev = [sum(busy_seconds(ctx.trace.ops.get(d, []), m.start, m.end)
-                   for m in program_intervals(ctx, d))
+    per_dev = [sum(busy_in(ctx.trace.ops.get(d, []),
+                           [(m.start, m.end)
+                            for m in program_intervals(ctx, d)]))
                for d in ctx.trace.modules]
     if not per_dev or max(per_dev) <= 0 or not ctx.iterations:
         return None

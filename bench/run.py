@@ -4,10 +4,11 @@
     python3 bench/run.py --workload <cell> --seed <n> --seconds <s> --trace <0|1>
 
 Set-up makes the cell's data on the device from ``--seed``, copies it
-to the host once (``PEMSVM.fit`` takes host arrays) and runs one whole
-warm-up fit, which compiles every program the window uses. The window
-then runs whole fits back to back until ``--seconds`` have passed and
-the fit in progress has returned. ``--trace 1`` records the window with
+to the host once (the estimator's ``fit`` takes host arrays), builds the
+estimator the configuration names and runs one whole warm-up fit, which
+compiles every program the window uses. The window then runs whole fits
+back to back until ``--seconds`` have passed and the fit in progress
+has returned. ``--trace 1`` records the window with
 the JAX profiler and reports the per-layer metrics instead of the
 end-to-end ones. After the window the reference fits the same data
 (made again from the seed) and every number compared is printed with
@@ -80,13 +81,11 @@ def run_cell(job, seconds: float, trace: bool, t_start: float = T_START,
     import numpy as np
     from jax.profiler import ProfileOptions, TraceAnnotation
 
-    from repro.core import PEMSVM
-
     mesh = harness.make_mesh(job)
     X, t = harness.make_data(job, mesh)
     X_host, t_host = np.asarray(X), np.asarray(t)
     del X, t
-    svm = PEMSVM(harness.svm_config(job), mesh=mesh)
+    svm = harness.estimator(job, mesh)
 
     def fit():
         return svm.fit(X_host, t_host)

@@ -8,7 +8,7 @@ import pytest
 
 sys.path.insert(0, str(Path(__file__).resolve().parents[2]))
 
-from bench import harness, peaks, tracefile  # noqa: E402
+from bench import harness, peaks, tracefile, work  # noqa: E402
 from bench.tracefile import Event, Trace  # noqa: E402
 
 K = "%fused_stats.7 = (f32[8,1]) custom-call(f32[8,896] %pad.1), custom_call_target=\"tpu_custom_call\""
@@ -90,11 +90,11 @@ def test_kernel_sums_per_iteration(ctx):
 
 def test_roofline_and_mfu_from_counts(ctx):
     fs = ctx.metric("fused_stats_roofline")
-    least, bound = fs.least_seconds(1024, ctx.job.width, ctx.peak)
+    least, bound = fs.least_seconds(ctx.job, ctx.peak)
     assert bound == "memory"
     assert ctx.value("fused_stats_roofline") == pytest.approx(
         100 * least / 0.5)
-    flops = ctx.metric("step_mfu").iteration_flops(ctx.job)
+    flops = work.iteration_flops(ctx.job)
     assert ctx.value("step_mfu") == pytest.approx(
         100 * flops / (0.65 * 4 * 197e12))
 
@@ -123,3 +123,79 @@ def test_metric_with_nothing_to_read_returns_none(ctx):
     assert one_chip.value("allreduce_ms") is None
     assert one_chip.value("step_ms") is None
     assert one_chip.value("prep_ms") is None
+
+
+def scan_breakdown(trace, device=0, top=10):
+    """The plain definition ``gap_breakdown`` sweeps: for each idle gap,
+    a scan of every host span for the innermost covering its midpoint."""
+    import collections
+
+    def activity(t):
+        inner = None
+        for e in trace.host:
+            if e.start <= t < e.end and (inner is None or e.dur < inner.dur):
+                inner = e
+        return inner.name if inner is not None else "outside any span"
+
+    lo, hi = trace.window
+    by = collections.Counter()
+    for a, b in tracefile.idle_gaps(trace.ops.get(device, []), lo, hi):
+        by[activity(0.5 * (a + b))] += b - a
+    return [[k, v] for k, v in by.most_common(top)]
+
+
+def random_trace(seed):
+    """Fits on a grid of quarter seconds, so gap midpoints fall on span
+    edges; each fit holds nested spans whose durations repeat, and some
+    spans start together with one duration. Time between fits holds no
+    span, or a lone one."""
+    import random
+
+    rng = random.Random(seed)
+    q = lambda k: 0.25 * k  # noqa: E731
+    host, ops, t = [], [], 0
+    for f in range(rng.randint(3, 12)):
+        n = rng.randint(8, 40)
+        host.append(Event("bench.fit", q(t), q(n)))
+        for s in range(rng.randint(1, 25)):
+            a = t + rng.randint(0, n - 1)
+            d = rng.choice((1, 2, 2, 3, 4, 8))
+            host.append(Event(f"span{rng.randint(0, 6)}", q(a), q(d)))
+            if rng.random() < 0.3:
+                host.append(Event(f"twin{s}", q(a), q(d)))
+        for _ in range(rng.randint(0, 12)):
+            a = t + rng.randint(0, n - 1)
+            ops.append(Event("%fusion.1 = f32[8] fusion(f32[8] %a)", q(a),
+                             q(rng.randint(1, 3))))
+        t += n + rng.randint(0, 6)
+        if rng.random() < 0.5:
+            host.append(Event("between", q(t - 1), q(2)))
+    rng.shuffle(host)
+    return Trace({0: ops}, {0: []}, host)
+
+
+@pytest.mark.parametrize("seed", range(40))
+def test_gap_breakdown_sweep_equals_scan(seed):
+    tr = random_trace(seed)
+    want = scan_breakdown(tr, top=50)
+    assert tracefile.gap_breakdown(tr, top=50) == want
+    assert tracefile.gap_breakdown(tr) == want[:10]
+
+
+@pytest.mark.parametrize("seed", range(20))
+def test_busy_in_equals_busy_seconds_per_interval(seed):
+    """``busy_in`` over program intervals gives, float for float, what
+    ``busy_seconds`` gives interval by interval: on quarter-second grids
+    where ops touch, nest, straddle an interval's edges and lie outside
+    every interval, containers among them."""
+    import random
+
+    rng = random.Random(seed)
+    q = lambda k: 0.25 * k + 0.1 * rng.random() * (k % 3 == 0)  # noqa: E731
+    ops = [Event(rng.choice((PAD, K, AR, LOOP)), q(rng.randint(0, 400)),
+                 q(rng.randint(0, 12))) for _ in range(rng.randint(0, 300))]
+    iv = sorted((q(a), q(a) + q(rng.randint(0, 30)))
+                for a in rng.sample(range(400), rng.randint(1, 30)))
+    want = [tracefile.busy_seconds(ops, a, b) for a, b in iv]
+    assert tracefile.busy_in(ops, iv) == want
+    assert tracefile.busy_in(ops, []) == []

@@ -9,8 +9,10 @@ events and the host's TraceMe spans line up (checked on a v5e: each
 from __future__ import annotations
 
 import collections
+import bisect
 import dataclasses
 import glob
+import heapq
 import os
 import re
 
@@ -111,6 +113,28 @@ def busy_seconds(events, lo: float, hi: float) -> float:
     return sum(b - a for a, b in merged(work(events), lo, hi))
 
 
+def busy_in(events, intervals) -> list[float]:
+    """``busy_seconds(events, a, b)`` for each (a, b) of ``intervals``,
+    from one union of the events' intervals: each interval's sum is of
+    the union's pieces clipped to it, the same pieces in the same order,
+    so the same floats, without a pass over every event per interval."""
+    if not intervals:
+        return []
+    union = merged(work(events), min(a for a, _ in intervals),
+                   max(b for _, b in intervals))
+    ends = [y for _, y in union]
+    out = []
+    for a, b in intervals:
+        total, i = 0, bisect.bisect_right(ends, a)
+        while i < len(union) and union[i][0] < b:
+            x, y = max(union[i][0], a), min(union[i][1], b)
+            if y > x:
+                total += y - x
+            i += 1
+        out.append(total)
+    return out
+
+
 def idle_gaps(events, lo: float, hi: float) -> list[tuple[float, float]]:
     """The intervals of [lo, hi] in which no op ran."""
     gaps, t = [], lo
@@ -123,22 +147,29 @@ def idle_gaps(events, lo: float, hi: float) -> list[tuple[float, float]]:
     return gaps
 
 
-def host_activity(host: list[Event], t: float) -> str:
-    """Name of the innermost host span that covers time ``t``."""
-    inner = None
-    for e in host:
-        if e.start <= t < e.end and (inner is None or e.dur < inner.dur):
-            inner = e
-    return inner.name if inner is not None else "outside any span"
-
-
 def gap_breakdown(trace: Trace, device: int = 0, top: int = 10):
     """Idle seconds of one device in the window, summed by the host span
-    the host was in at each gap's midpoint; the ``top`` largest."""
+    the host was in at each gap's midpoint t: the innermost span with
+    start <= t < end, of equal durations the first in ``trace.host``, or
+    "outside any span"; the ``top`` largest.
+
+    One sweep over the spans sorted by start and the gaps in time order:
+    a heap holds the spans begun by t, keyed by (duration, list index),
+    and a span that has ended is dropped once it comes to the top."""
     lo, hi = trace.window
+    host = trace.host
+    order = sorted(range(len(host)), key=lambda i: host[i].start)
+    begun: list[tuple[float, int]] = []
+    j = 0
     by = collections.Counter()
     for a, b in idle_gaps(trace.ops.get(device, []), lo, hi):
-        by[host_activity(trace.host, 0.5 * (a + b))] += b - a
+        t = 0.5 * (a + b)
+        while j < len(order) and host[order[j]].start <= t:
+            heapq.heappush(begun, (host[order[j]].dur, order[j]))
+            j += 1
+        while begun and host[begun[0][1]].end <= t:
+            heapq.heappop(begun)
+        by[host[begun[0][1]].name if begun else "outside any span"] += b - a
     return [[k, v] for k, v in by.most_common(top)]
 
 
