@@ -46,6 +46,7 @@ import dataclasses
 import numpy as np
 
 import jax.numpy as jnp
+from jax.profiler import TraceAnnotation
 
 from . import kernel as krn
 from .linear import PhiSpec
@@ -63,13 +64,26 @@ def nystrom_projection(landmarks: np.ndarray, *, kind: str = "rbf",
     decomposition the Nyström path ever runs — fit computes it once and
     caches it; prediction reuses it.
     """
-    K_mm = np.asarray(krn.gram_matrix(
+    return _inverse_sqrt(_landmark_gram(landmarks, kind, sigma, backend),
+                         spectral_floor)[0]
+
+
+def _landmark_gram(landmarks, kind: str, sigma: float,
+                   backend: str | None) -> np.ndarray:
+    """K_mm (m, m) float64, computed by the kernels backend in float32."""
+    return np.asarray(krn.gram_matrix(
         jnp.asarray(landmarks), jnp.asarray(landmarks), kind=kind,
         sigma=sigma, backend=backend), np.float64)
+
+
+def _inverse_sqrt(K_mm: np.ndarray,
+                  spectral_floor: float) -> tuple[np.ndarray, int]:
+    """(K_mm^{-1/2} over the eigenvalues above ``spectral_floor`` times
+    the largest, how many eigenvalues that keeps)."""
     w, V = np.linalg.eigh(0.5 * (K_mm + K_mm.T))
     floor = spectral_floor * max(w.max(), 1e-30)
     keep = w > floor
-    return (V[:, keep] / np.sqrt(w[keep])) @ V[:, keep].T
+    return (V[:, keep] / np.sqrt(w[keep])) @ V[:, keep].T, int(keep.sum())
 
 
 def nystrom_features(X: np.ndarray, landmarks: np.ndarray, *,
@@ -131,12 +145,17 @@ class NystromSVM:
         """The one-time host-side setup: cache the landmark strip and
         K_mm^{-1/2}, and hand both to the delegate's device path.
         ``eigh`` runs exactly once per fit; predict/score/
-        decision_function reuse the cache."""
-        self._landmarks = np.asarray(landmarks, np.float32)
-        self._proj = nystrom_projection(
-            self._landmarks, kind=self.kernel_kind, sigma=self.sigma,
-            spectral_floor=self.spectral_floor,
-            backend=self.svm.config.backend).astype(np.float32)
+        decision_function reuse the cache. Span ``nystrom.projection``
+        (landmarks, rank): K_mm, its eigendecomposition and the cast."""
+        with TraceAnnotation("nystrom.projection",
+                             landmarks=len(landmarks)) as span:
+            self._landmarks = np.asarray(landmarks, np.float32)
+            proj, rank = _inverse_sqrt(
+                _landmark_gram(self._landmarks, self.kernel_kind,
+                               self.sigma, self.svm.config.backend),
+                self.spectral_floor)
+            self._proj = proj.astype(np.float32)
+            span.set_metadata(rank=rank)
         self.svm._phi_arrays = (self._landmarks, self._proj)
 
     @staticmethod
@@ -151,14 +170,22 @@ class NystromSVM:
         """``fit_kw`` forwards the elastic surface (resume_from /
         warm_start / fault_hook / ...) — see ``PEMSVM.fit``. Landmark
         selection is seed-deterministic, and is skipped entirely when
-        continuing a fit whose featurizer is already installed."""
-        X = np.asarray(X, np.float32)
-        if not (self._continuing(fit_kw) and self._landmarks is not None):
+        continuing a fit whose featurizer is already installed. Span
+        ``nystrom.landmarks`` (rows, landmarks): the float32 view of X
+        and the seeded row draw."""
+        landmarks = None
+        with TraceAnnotation("nystrom.landmarks") as span:
+            X = np.asarray(X, np.float32)
             N = X.shape[0]
-            m = self.n_landmarks or int(np.ceil(np.sqrt(N)))
-            rng = np.random.default_rng(self.seed)
-            self._install_featurizer(
-                X[rng.choice(N, size=min(m, N), replace=False)])
+            if not (self._continuing(fit_kw)
+                    and self._landmarks is not None):
+                m = self.n_landmarks or int(np.ceil(np.sqrt(N)))
+                rng = np.random.default_rng(self.seed)
+                landmarks = X[rng.choice(N, size=min(m, N), replace=False)]
+            span.set_metadata(rows=N, landmarks=(
+                0 if landmarks is None else len(landmarks)))
+        if landmarks is not None:
+            self._install_featurizer(landmarks)
         return self.svm.fit(X, y, **fit_kw)
 
     def fit_libsvm(self, path: str, n_features: int,

@@ -68,7 +68,7 @@ from . import epilogues
 
 def _make_kernel(epilogue: str, eps: float, eps_ins: float,
                  n_noise: int, n_aug: int, windowed: bool = False,
-                 rng: bool = False, n_chains: int = 1):
+                 rng: bool = False, n_chains: int = 1, precision=None):
     def _kernel(*refs):
         if rng:
             seed_ref, refs = refs[0], refs[1:]
@@ -88,7 +88,7 @@ def _make_kernel(epilogue: str, eps: float, eps_ins: float,
 
         margin = jax.lax.dot_general(                # (bn, C) on the MXU
             x, wv, dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            precision=precision, preferred_element_type=jnp.float32)
         margin_ref[...] = margin
         if rng:                                      # in-kernel counter RNG
             noise = epilogues.fused_noise(
@@ -108,7 +108,7 @@ def _make_kernel(epilogue: str, eps: float, eps_ins: float,
 
         b_ref[...] += jax.lax.dot_general(           # x^T coef: (K, C)
             x, coef, dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            precision=precision, preferred_element_type=jnp.float32)
         if windowed:                                 # aligned column window
             a0 = pl.multiple_of(c0_ref[0], 128)
             xc = x_ref[:, pl.ds(a0, s_ref.shape[1])].astype(jnp.float32)
@@ -118,7 +118,7 @@ def _make_kernel(epilogue: str, eps: float, eps_ins: float,
             xw = x * (wmask * weight)                # (bn, K) weighted rows
             s_ref[...] += jax.lax.dot_general(       # x^T diag(m*w) x[:, w]
                 xw, xc, dimension_numbers=(((0,), (0,)), ((), ())),
-                preferred_element_type=jnp.float32)
+                precision=precision, preferred_element_type=jnp.float32)
         else:
             # One Sigma block per chain, laid side by side in a 2-D
             # (Kp, C*Kp) accumulator: static per-chain column slices
@@ -131,6 +131,7 @@ def _make_kernel(epilogue: str, eps: float, eps_ins: float,
                 xw = x * (wmask * weight[:, c:c + 1])
                 s_ref[:, c * cw:(c + 1) * cw] += jax.lax.dot_general(
                     xw, xc, dimension_numbers=(((0,), (0,)), ((), ())),
+                    precision=precision,
                     preferred_element_type=jnp.float32)
     return _kernel
 
@@ -153,7 +154,8 @@ def aligned_window_base(col_start, Kp: int, Cw: int):
 
 @functools.partial(jax.jit,
                    static_argnames=("epilogue", "eps", "eps_ins",
-                                    "block_n", "col_blk", "interpret"))
+                                    "block_n", "col_blk", "precision",
+                                    "interpret"))
 def fused_stats(X: jnp.ndarray, rho: jnp.ndarray, beta: jnp.ndarray,
                 wvec: jnp.ndarray, wmask: jnp.ndarray | None = None,
                 noise: tuple | None = None,
@@ -161,7 +163,7 @@ def fused_stats(X: jnp.ndarray, rho: jnp.ndarray, beta: jnp.ndarray,
                 seed: jnp.ndarray | None = None, *,
                 epilogue: str = "em_hinge", eps: float = 1e-6,
                 eps_ins: float = 0.0, block_n: int = 512,
-                col_blk: int | None = None,
+                col_blk: int | None = None, precision=None,
                 interpret: bool = False):
     """Returns (margin (N,), *aug (N,) each, b (K,), S), all f32 — aug
     is (gamma,) for the hinge epilogues, (gamma, omega) for SVR. S is
@@ -186,6 +188,9 @@ def fused_stats(X: jnp.ndarray, rho: jnp.ndarray, beta: jnp.ndarray,
     0, and their X-row is 0 so the b/S contributions vanish regardless
     of the augmentation values (SVR's MC coef is nonzero on padded rows
     — the zero X-row alone makes it a no-op).
+
+    ``precision`` (a ``jax.lax.Precision``) is that of the margin, b and
+    Sigma dots; None is the TPU's default, one bf16 pass.
     """
     N, K = X.shape
     multi = wvec.ndim == 2
@@ -243,7 +248,7 @@ def fused_stats(X: jnp.ndarray, rho: jnp.ndarray, beta: jnp.ndarray,
     chn_spec = pl.BlockSpec((bn, C), lambda n: (n, 0))
     outs = pl.pallas_call(
         _make_kernel(epilogue, float(eps), float(eps_ins), n_noise,
-                     n_aug, windowed, rng, C),
+                     n_aug, windowed, rng, C, precision),
         grid=grid,
         in_specs=extra_specs + [                        # [seed] [base]
             pl.BlockSpec((bn, Kp), lambda n: (n, 0)),   # X rows

@@ -51,12 +51,19 @@ from jax.experimental.pallas import tpu as pltpu
 
 from . import epilogues
 from .fused_stats import aligned_window_base, col_window_geometry
-from .rbf_gram import rbf_tile
+from .rbf_gram import COMPILER_PARAMS, rbf_tile
+
+HIGHEST = jax.lax.Precision.HIGHEST
 
 
 def _phi_tile(x, lm, pj, maskv, *, kind: str, inv_two_sigma_sq: float,
               bias_col: int | None):
     """One (bn, M) phi tile from a (bn, D) X tile, entirely in VMEM.
+
+    The map is float32: the cross-Gram and the projection dots run at
+    HIGHEST precision, since K_mm^{-1/2} has entries up to
+    floor^{-1/2} and amplifies a bf16 pass's rounding into a different
+    model.
 
     x: (bn, Dp); lm: (Lp, Dp) landmark strip; pj: (Lp, Wp) projection
     (zero-padded rows/cols are exact no-ops); maskv: (bn, 1).
@@ -68,12 +75,12 @@ def _phi_tile(x, lm, pj, maskv, *, kind: str, inv_two_sigma_sq: float,
     elif kind == "linear":  # the cross-Gram IS the inner product
         kmat = jax.lax.dot_general(
             x, lm, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            precision=HIGHEST, preferred_element_type=jnp.float32)
     else:  # match the ref oracle: never silently fall through
         raise ValueError(f"unknown kernel kind {kind!r}")
     phi = jax.lax.dot_general(                               # (bn, Wp)
         kmat, pj, dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        precision=HIGHEST, preferred_element_type=jnp.float32)
     if bias_col is not None:
         cols = jax.lax.broadcasted_iota(jnp.int32, phi.shape, 1)
         phi = phi + jnp.where(cols == bias_col, 1.0, 0.0)
@@ -146,10 +153,12 @@ def _make_fused_kernel(kind: str, inv_two_sigma_sq: float,
         beta = beta_ref[...].astype(jnp.float32)             # (bn, 1)
         wv = w_ref[...].astype(jnp.float32)                  # (Wp, 1)
 
-        # From here this is exactly fused_stats' tile body with X := phi.
+        # From here this is fused_stats' tile body with X := phi, its dots
+        # at HIGHEST: the RBF features are strongly correlated, so a bf16
+        # pass over Sigma = phi^T Gamma^-1 phi moves the EM solution.
         margin = jax.lax.dot_general(
             phi, wv, dimension_numbers=(((1,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            precision=HIGHEST, preferred_element_type=jnp.float32)
         margin_ref[...] = margin
         if rng:                                  # in-kernel counter RNG
             noise = epilogues.fused_noise(
@@ -169,7 +178,7 @@ def _make_fused_kernel(kind: str, inv_two_sigma_sq: float,
 
         b_ref[...] += jax.lax.dot_general(                   # phi^T coef
             phi, coef, dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            precision=HIGHEST, preferred_element_type=jnp.float32)
         pw = phi * (maskv * weight)                          # weighted rows
         if windowed:                    # aligned phi-column window, VMEM
             # The TPU kernel compiler slices refs, not loaded values, at
@@ -181,7 +190,7 @@ def _make_fused_kernel(kind: str, inv_two_sigma_sq: float,
             pc = phi
         s_ref[...] += jax.lax.dot_general(                   # phi^T D phi_w
             pw, pc, dimension_numbers=(((0,), (0,)), ((), ())),
-            preferred_element_type=jnp.float32)
+            precision=HIGHEST, preferred_element_type=jnp.float32)
     return _kernel
 
 
@@ -232,6 +241,7 @@ def nystrom_phi(X: jnp.ndarray, landmarks: jnp.ndarray, proj: jnp.ndarray,
         ],
         out_specs=pl.BlockSpec((bn, Wp), lambda n: (n, 0)),
         out_shape=jax.ShapeDtypeStruct((Np, Wp), jnp.float32),
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
     )(X, landmarks, proj, mask.reshape(Np, 1))
     return out[:N, :M]
@@ -276,6 +286,7 @@ def nystrom_score(X: jnp.ndarray, landmarks: jnp.ndarray,
         ],
         out_specs=pl.BlockSpec((bn, Cp), lambda n: (n, 0)),
         out_shape=jax.ShapeDtypeStruct((Np, Cp), jnp.float32),
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
     )(X, landmarks, proj, mask.reshape(Np, 1), Wmat)
     return out[:N, :C]
@@ -384,6 +395,7 @@ def nystrom_fused_stats(X: jnp.ndarray, landmarks: jnp.ndarray,
         ],
         scratch_shapes=([pltpu.VMEM((bn, Wp), jnp.float32)] if windowed
                         else []),
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
     )(*extra_ops, X, landmarks, proj, mask.reshape(Np, 1),
       rho.reshape(Np, 1), beta.reshape(Np, 1), wvec.reshape(Wp, 1),

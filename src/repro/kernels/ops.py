@@ -77,29 +77,32 @@ def weighted_gram(X: jnp.ndarray, w: jnp.ndarray, *,
         X, w, interpret=(backend == "interpret"), **kw)
 
 
-def syrk_tri(X: jnp.ndarray, w: jnp.ndarray, *,
+def syrk_tri(X: jnp.ndarray, w: jnp.ndarray, *, precision=None,
              backend: str | None = None, **kw) -> jnp.ndarray:
     """S = X^T diag(w) X computing only lower-triangle blocks (~2x fewer
     FLOPs than ``weighted_gram``); result is the full symmetric matrix."""
     backend = _resolve(backend)
     if backend == "ref":
-        return ref.syrk_tri(X, w)
-    return _syrk.syrk_tri(X, w, interpret=(backend == "interpret"), **kw)
+        return ref.syrk_tri(X, w, precision)
+    return _syrk.syrk_tri(X, w, precision=precision,
+                          interpret=(backend == "interpret"), **kw)
 
 
 def _ru(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
 
 
-# No kernel raises its VMEM limit, so each runs under the TPU
-# compiler's default scoped limit (16 MiB on v5e); a working set past
-# it is refused at compile time (RESOURCE_EXHAUSTED in vmem).
+# The LIN kernels do not raise their VMEM limit, so each runs under the
+# TPU compiler's default scoped limit (16 MiB on v5e); a working set
+# past it is refused at compile time (RESOURCE_EXHAUSTED in vmem). The
+# Nystrom kernels and ``rbf_gram`` raise theirs (``_NYSTROM_VMEM_BUDGET``).
 _SCOPED_VMEM_BYTES = 16 * 2 ** 20
 
 
 def _fused_stats_vmem_bytes(n_features: int, col_blk: int | None,
                             block_n: int, epilogue: str,
-                            n_chains: int = 1, x_bytes: int = 4) -> int:
+                            n_chains: int = 1, x_bytes: int = 4,
+                            precision=None) -> int:
     """Upper bound on the scoped VMEM of one ``fused_stats`` grid step
     (DESIGN.md §Perf, "VMEM accounting"), as Pallas lays it out:
 
@@ -110,7 +113,10 @@ def _fused_stats_vmem_bytes(n_features: int, col_blk: int | None,
       * the epilogue's noise vectors twice as well — streamed operands,
         or under the in-kernel RNG the same-shaped cipher temporaries;
       * the (Kp, C*Cw) Sigma accumulator once, plus one (bn, Kp) f32
-        weighted-row temporary (and the f32 cast of a narrower X tile).
+        weighted-row temporary (and the f32 cast of a narrower X tile);
+      * at a ``precision`` above the default, the dots' operands split
+        into bf16 parts, 1.5 words an entry: the X tile, the weighted
+        rows and the (bn, Cw) Sigma column block.
 
     Checked against the compiler's own figures at the boundary (the
     largest admitted shape compiles, ``tests/test_tpu_compile.py``)."""
@@ -122,19 +128,22 @@ def _fused_stats_vmem_bytes(n_features: int, col_blk: int | None,
     streamed = block_n * Kp * x_bytes + 4 * (block_n * rows
                                              + 2 * Kp * lanes)
     temps = 4 * block_n * Kp * (1 + (x_bytes < 4))
+    if precision not in (None, jax.lax.Precision.DEFAULT):
+        temps += 6 * block_n * (2 * Kp + Cw)
     return 2 * streamed + 4 * Kp * n_chains * Cw + temps
 
 
 def fused_stats_fits(n_features: int, col_blk: int | None = None,
                      block_n: int = 512,
                      epilogue: str = "em_hinge",
-                     n_chains: int = 1, x_bytes: int = 4) -> bool:
+                     n_chains: int = 1, x_bytes: int = 4,
+                     precision=None) -> bool:
     """Whether the one-pass fused-statistic kernel fits the default
     scoped VMEM limit. A column window narrows the accumulator to
     (K, Cw), so K beyond the full-width cap can still fuse."""
     return _fused_stats_vmem_bytes(n_features, col_blk, block_n,
-                                   epilogue, n_chains,
-                                   x_bytes) <= _SCOPED_VMEM_BYTES
+                                   epilogue, n_chains, x_bytes,
+                                   precision) <= _SCOPED_VMEM_BYTES
 
 
 # Largest full-width K (a lane multiple) the single-chain kernel takes
@@ -148,7 +157,7 @@ def fused_stats(X: jnp.ndarray, rho: jnp.ndarray, beta: jnp.ndarray,
                 noise: tuple | None = None, *,
                 epilogue: str = "em_hinge", eps: float = 1e-6,
                 eps_ins: float = 0.0, col_window: tuple | None = None,
-                seed: jnp.ndarray | None = None,
+                seed: jnp.ndarray | None = None, precision=None,
                 backend: str | None = None, **kw):
     """(margin, *aug, b, S): the whole iteration statistic in one X
     pass (single HBM stream instead of the split margin/b/Sigma
@@ -170,10 +179,14 @@ def fused_stats(X: jnp.ndarray, rho: jnp.ndarray, beta: jnp.ndarray,
 
     When the working set exceeds the scoped VMEM limit
     (``fused_stats_fits``: full width past FUSED_STATS_MAX_K, fewer K
-    for C chains, or a window too wide) the Pallas flavors
-    fall back to the K-tiled split pair (E-step + syrk_tri; windowed:
-    plain-XLA column block) rather than blow VMEM — callers get the
-    same outputs either way."""
+    for C chains or at a higher ``precision``, or a window too wide)
+    the Pallas flavors fall back to the K-tiled split pair (E-step +
+    syrk_tri; windowed: plain-XLA column block) rather than blow VMEM —
+    callers get the same outputs either way.
+
+    ``precision`` (a ``jax.lax.Precision``) is that of every dot of the
+    statistic, on each path: None, the TPU's default single bf16 pass,
+    for LIN; the Nystrom fallback asks for HIGHEST."""
     backend = _resolve(backend)
     _check_noise(epilogue, noise, seed)
     multi = wvec.ndim == 2
@@ -182,11 +195,11 @@ def fused_stats(X: jnp.ndarray, rho: jnp.ndarray, beta: jnp.ndarray,
         return ref.fused_stats(X, rho, beta, wvec, wmask, eps,
                                epilogue=epilogue, noise=noise,
                                eps_ins=eps_ins, col_window=col_window,
-                               seed=seed)
+                               seed=seed, precision=precision)
     fits = functools.partial(
         fused_stats_fits, X.shape[1], block_n=kw.get("block_n", 512),
         epilogue=epilogue, n_chains=n_chains,
-        x_bytes=jnp.dtype(X.dtype).itemsize)
+        x_bytes=jnp.dtype(X.dtype).itemsize, precision=precision)
     if col_window is not None:
         start, blk = col_window
         if not fits(blk):
@@ -198,11 +211,12 @@ def fused_stats(X: jnp.ndarray, rho: jnp.ndarray, beta: jnp.ndarray,
             return ref.fused_stats(X, rho, beta, wvec, wmask, eps,
                                    epilogue=epilogue, noise=noise,
                                    eps_ins=eps_ins,
-                                   col_window=col_window, seed=seed)
+                                   col_window=col_window, seed=seed,
+                                   precision=precision)
         return _fused_stats.fused_stats(
             X, rho, beta, wvec, wmask, noise, start, seed,
             epilogue=epilogue, eps=eps, eps_ins=eps_ins, col_blk=blk,
-            interpret=(backend == "interpret"), **kw)
+            precision=precision, interpret=(backend == "interpret"), **kw)
     if not fits(None):
         kw.pop("block_n", None)
         if multi:
@@ -210,7 +224,8 @@ def fused_stats(X: jnp.ndarray, rho: jnp.ndarray, beta: jnp.ndarray,
             # are plain XLA matmuls (compute-bound regime).
             return ref.fused_stats(X, rho, beta, wvec, wmask, eps,
                                    epilogue=epilogue, noise=noise,
-                                   eps_ins=eps_ins, seed=seed)
+                                   eps_ins=eps_ins, seed=seed,
+                                   precision=precision)
         # Split fallback: the O(NK) E-step (margin, aug, coef) runs as
         # plain XLA; only the O(NK^2) Sigma goes through the K-tiled
         # SYRK kernel, whose blocks fit VMEM at any K. 3 X streams —
@@ -219,17 +234,19 @@ def fused_stats(X: jnp.ndarray, rho: jnp.ndarray, beta: jnp.ndarray,
         if seed is not None:
             noise = ref.seed_noise(seed, X.shape[0], 1, epilogue)
         Xf = X.astype(jnp.float32)
-        margin = Xf @ wvec.astype(jnp.float32)
+        margin = jnp.matmul(Xf, wvec.astype(jnp.float32),
+                            precision=precision)
         aug, weight, coef = epilogues.apply_epilogue(
             epilogue, margin, rho.astype(jnp.float32),
             beta.astype(jnp.float32), noise, eps, eps_ins)
         w = weight if wmask is None else wmask.astype(jnp.float32) * weight
-        b = Xf.T @ coef
-        return (margin, *aug, b, syrk_tri(X, w, backend=backend))
+        b = jnp.matmul(Xf.T, coef, precision=precision)
+        return (margin, *aug, b, syrk_tri(X, w, precision=precision,
+                                          backend=backend))
     return _fused_stats.fused_stats(
         X, rho, beta, wvec, wmask, noise, None, seed,
-        epilogue=epilogue, eps=eps,
-        eps_ins=eps_ins, interpret=(backend == "interpret"), **kw)
+        epilogue=epilogue, eps=eps, eps_ins=eps_ins, precision=precision,
+        interpret=(backend == "interpret"), **kw)
 
 
 def fused_estep(X: jnp.ndarray, rho: jnp.ndarray, beta: jnp.ndarray,
@@ -261,39 +278,58 @@ def rbf_gram(X1: jnp.ndarray, X2: jnp.ndarray, *, sigma: float = 1.0,
 # regime anyway: at large m the statistic turns compute-bound and the
 # fusion's HBM saving stops mattering (DESIGN.md §Perf/Nystrom).
 NYSTROM_FUSED_MAX_M = 1024
-_NYSTROM_VMEM_BUDGET = 14 * 2 ** 20
+# The Nystrom kernels raise their scoped VMEM limit to this figure.
+_NYSTROM_VMEM_BUDGET = _rbf_gram.VMEM_LIMIT_BYTES
 
 
 def _nystrom_vmem_words(n_landmarks: int, n_features: int, add_bias: bool,
                         block_n: int, with_stats: bool,
                         epilogue: str = "em_hinge",
                         col_blk: int | None = None,
-                        rng: bool = False) -> int:
-    """fp32 words resident per grid step of the Nystrom kernels
-    (DESIGN.md §Perf/Nystrom accounting). ``with_stats`` adds the
-    Sigma/b accumulators only the fused flavor allocates; the epilogue
-    adds its pre-drawn noise operands and extra aug outputs (per-row
-    vectors — noise next to the phi tile, but accounted). ``col_blk``
-    narrows the Sigma accumulator to its aligned (Wp, Cw) k-shard
-    column window."""
+                        rng: bool = False, score_cols: int = 0) -> int:
+    """Upper bound on the f32 words of scoped VMEM one grid step of a
+    Nystrom kernel holds (DESIGN.md §Perf/Nystrom accounting), padded
+    dims (Lp landmarks, Dp features, Wp phi columns):
+
+      * every block twice (double-buffered), each narrow one padded to
+        128 lanes: the (bn, Dp) X tile, the mask, the (Lp, Dp) landmark
+        strip and the (Lp, Wp) projection; then ``with_stats``: rho,
+        beta, margin, the epilogue's aug outputs and pre-drawn noise
+        (none under the in-kernel RNG), w, b and the (Wp, Cw) Sigma
+        accumulator (a k-shard ``col_blk`` window narrows Cw); or
+        ``score_cols``: the (Wp, Cp) weight block and the (bn, Cp)
+        score tile; or else the (bn, Wp) phi tile written out;
+      * the (bn, Lp) cross tile and the (bn, Wp) phi tile once, and with
+        the statistic its weighted rows (and the phi tile staged for a
+        window load);
+      * the HIGHEST-precision dots' operands split into three bf16
+        parts, 1.5 words an entry: X tile, strip, cross tile and
+        projection, and with the statistic phi, its weighted rows and
+        the Sigma column window.
+
+    The compiler's own figure for the same shapes stays under this
+    bound; the boundary the budget draws compiles
+    (``tests/test_tpu_compile.py``)."""
     Lp = _ru(n_landmarks, 128)
     Dp = _ru(n_features, 128)
     Wp = _ru(n_landmarks + int(add_bias), 128)
-    words = (block_n * Dp        # X tile
-             + Lp * Dp           # landmark strip
-             + Lp * Wp           # projection
-             + block_n * Lp      # cross-Gram tile
-             + block_n * Wp)     # phi tile
+    blocks = block_n * Dp + Lp * Dp + Lp * Wp + 128 * block_n    # X..mask
+    temps = block_n * Lp + block_n * Wp                    # cross, phi
+    split = block_n * Dp + Lp * Dp + block_n * Lp + Lp * Wp
     if with_stats:
-        per_row = (4                               # mask/rho/beta/margin
+        per_row = (3                                       # rho/beta/margin
                    + (0 if rng else epilogues.noise_arity(epilogue))
                    + epilogues.aug_arity(epilogue))
         Cw = Wp if col_blk is None else min(Wp, _ru(col_blk, 128) + 128)
-        words += (Wp * Cw        # Sigma accumulator (windowed: narrowed)
-                  + Wp + per_row * block_n)  # w/b + per-row vectors
-        if col_blk is not None:
-            words += block_n * Wp  # phi staged for the window load
-    return words
+        blocks += 128 * block_n * per_row + 2 * 128 * Wp + Wp * Cw
+        temps += block_n * Wp * (1 + (col_blk is not None))
+        split += 2 * block_n * Wp + block_n * Cw
+    elif score_cols:
+        Cp = _ru(score_cols, 128)
+        blocks += Wp * Cp + block_n * Cp
+    else:
+        blocks += block_n * Wp
+    return 2 * blocks + temps + 3 * split // 2
 
 
 def nystrom_fused_fits(n_landmarks: int, n_features: int,
@@ -348,16 +384,14 @@ def nystrom_score_fits(n_landmarks: int, n_features: int,
                        n_score_cols: int, add_bias: bool = False,
                        block_n: int = 256) -> bool:
     """Whether the fused scoring epilogue's working set fits VMEM: the
-    featurize-only set plus the resident (Wp, Cp) weight block and the
-    (bn, Cp) score tile (serving's only HBM write)."""
+    featurize-only set with the resident (Wp, Cp) weight block and the
+    (bn, Cp) score tile (serving's only HBM write) in place of the phi
+    tile written out."""
     if n_landmarks > NYSTROM_FUSED_MAX_M:
         return False
-    Wp = _ru(n_landmarks + int(add_bias), 128)
-    Cp = _ru(n_score_cols, 128)
-    words = (_nystrom_vmem_words(n_landmarks, n_features, add_bias,
-                                 block_n, False)
-             + Wp * Cp + block_n * Cp)
-    return 4 * words <= _NYSTROM_VMEM_BUDGET
+    return 4 * _nystrom_vmem_words(
+        n_landmarks, n_features, add_bias, block_n, False,
+        score_cols=n_score_cols) <= _NYSTROM_VMEM_BUDGET
 
 
 def nystrom_score(X: jnp.ndarray, landmarks: jnp.ndarray,
@@ -409,7 +443,9 @@ def nystrom_fused_stats(X: jnp.ndarray, landmarks: jnp.ndarray,
     (``nystrom_fused_fits``), falls back to featurize-then-accumulate:
     nystrom_phi materializes phi for this row block and fused_stats
     (K-tiled past its own cap, window passed through) consumes it under
-    the same epilogue — callers get the same outputs either way."""
+    the same epilogue — callers get the same outputs either way: both
+    paths run every dot at HIGHEST (the statistic of RBF features, which
+    are strongly correlated, moves the EM solution at one bf16 pass)."""
     backend = _resolve(backend)
     _check_noise(epilogue, noise, seed)
     if backend == "ref":
@@ -427,7 +463,7 @@ def nystrom_fused_stats(X: jnp.ndarray, landmarks: jnp.ndarray,
         return fused_stats(phi, rho, beta, wvec, mask, noise,
                            epilogue=epilogue, eps=eps, eps_ins=eps_ins,
                            col_window=col_window, seed=seed,
-                           backend=backend)
+                           precision=_nystrom_phi.HIGHEST, backend=backend)
     if col_window is not None:
         start, blk = col_window
         return _nystrom_phi.nystrom_fused_stats(

@@ -14,6 +14,16 @@ import functools
 import jax
 import jax.numpy as jnp
 from jax.experimental import pallas as pl
+from jax.experimental.pallas import tpu as pltpu
+
+# Scoped VMEM for the kernels whose RBF tile runs its dots at HIGHEST
+# (this Gram, and the Nystrom kernels in ``nystrom_phi.py``), in place
+# of the compiler's 16 MiB default (v5e has 128 MiB): the least whole
+# 8 MiB that holds ``ops._nystrom_vmem_words`` (double-buffered blocks,
+# the HIGHEST dots' operand splits) at the landmark cap, m = 1024, and
+# D = 256. ``ops.nystrom_fused_fits`` budgets against this figure.
+VMEM_LIMIT_BYTES = 48 * 2 ** 20
+COMPILER_PARAMS = pltpu.CompilerParams(vmem_limit_bytes=VMEM_LIMIT_BYTES)
 
 
 def rbf_tile(x1: jnp.ndarray, x2: jnp.ndarray,
@@ -23,11 +33,15 @@ def rbf_tile(x1: jnp.ndarray, x2: jnp.ndarray,
 
     Shared by ``rbf_gram`` and the fused Nystrom featurize kernel
     (``nystrom_phi.py``), so the two paths cannot drift numerically.
+    The cross term runs at HIGHEST precision: ``sq1 - 2 x1.x2 + sq2``
+    cancels for nearby rows, and one bf16 pass (the TPU's default for
+    a float32 dot) would leave errors the size of the distance itself.
     """
     sq1 = jnp.sum(x1 * x1, axis=1, keepdims=True)          # (b1, 1)
     sq2 = jnp.sum(x2 * x2, axis=1, keepdims=True)          # (b2, 1)
     cross = jax.lax.dot_general(                            # (b1, b2)
         x1, x2, dimension_numbers=(((1,), (1,)), ((), ())),
+        precision=jax.lax.Precision.HIGHEST,
         preferred_element_type=jnp.float32)
     d2 = jnp.maximum(sq1 - 2.0 * cross + sq2.T, 0.0)
     return jnp.exp(-d2 * inv_two_sigma_sq)
@@ -72,6 +86,7 @@ def rbf_gram(X1: jnp.ndarray, X2: jnp.ndarray, *, sigma: float = 1.0,
         ],
         out_specs=pl.BlockSpec((b1, b2), lambda i, j: (i, j)),
         out_shape=jax.ShapeDtypeStruct((N1p, N2p), jnp.float32),
+        compiler_params=COMPILER_PARAMS,
         interpret=interpret,
     )(X1, X2)
     return out[:N1, :N2]

@@ -1,9 +1,14 @@
 """Pure-jnp reference oracles for every Pallas kernel in this package.
 
 These are the ground truth used by tests (assert_allclose vs interpret-mode
-Pallas) and the default CPU execution path of ``ops.py``.
+Pallas) and the default CPU execution path of ``ops.py``. Each oracle's
+dots run at the precision of the kernel it stands for: the Nystrom map
+at HIGHEST, the LIN statistic at ``precision`` (None: the TPU's
+default, one bf16 pass).
 """
 from __future__ import annotations
+
+import functools
 
 import jax
 import jax.numpy as jnp
@@ -29,7 +34,11 @@ def seed_noise(seed, n: int, n_chains: int, epilogue: str):
                              epilogues.noise_arity(epilogue))
 
 
-def weighted_gram(X: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
+HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def weighted_gram(X: jnp.ndarray, w: jnp.ndarray,
+                  precision=None) -> jnp.ndarray:
     """S = X^T diag(w) X  == sum_d w_d x_d x_d^T.
 
     The paper's rate-limiting statistic (its Table-9 GPU kernel).
@@ -43,7 +52,7 @@ def weighted_gram(X: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
     """
     Xf = X.astype(jnp.float32)
     wf = w.astype(jnp.float32)
-    return (Xf * wf[:, None]).T @ Xf
+    return jnp.matmul((Xf * wf[:, None]).T, Xf, precision=precision)
 
 
 def fused_estep(X: jnp.ndarray, rho: jnp.ndarray, beta: jnp.ndarray,
@@ -71,11 +80,12 @@ def fused_estep(X: jnp.ndarray, rho: jnp.ndarray, beta: jnp.ndarray,
     return margin, gamma, b
 
 
-def syrk_tri(X: jnp.ndarray, w: jnp.ndarray) -> jnp.ndarray:
+def syrk_tri(X: jnp.ndarray, w: jnp.ndarray,
+             precision=None) -> jnp.ndarray:
     """Oracle for the triangle-blocked SYRK — identical mathematical
     content to ``weighted_gram``; the Pallas flavor merely skips the
     redundant upper-triangle block computations."""
-    return weighted_gram(X, w)
+    return weighted_gram(X, w, precision)
 
 
 def fused_stats(X: jnp.ndarray, rho: jnp.ndarray, beta: jnp.ndarray,
@@ -83,7 +93,7 @@ def fused_stats(X: jnp.ndarray, rho: jnp.ndarray, beta: jnp.ndarray,
                 eps: float, epilogue: str = "em_hinge",
                 noise: tuple | None = None, eps_ins: float = 0.0,
                 col_window: tuple | None = None,
-                seed: jnp.ndarray | None = None):
+                seed: jnp.ndarray | None = None, precision=None):
     """One-sweep iteration statistic under any augmentation epilogue:
     margin -> (aug, sigma_weight, coef) -> (b, Sigma) in one logical
     pass (``kernels/epilogues.py`` holds the epilogue family; MC
@@ -108,6 +118,7 @@ def fused_stats(X: jnp.ndarray, rho: jnp.ndarray, beta: jnp.ndarray,
       (gamma,) for the hinge epilogues, (gamma, omega) for SVR; S is
       (K, K) full or (K, blk) windowed.
     """
+    dot = functools.partial(jnp.matmul, precision=precision)
     Xf = X.astype(jnp.float32)
     if wvec.ndim == 2:
         assert seed is not None, "multichain fused_stats requires seed"
@@ -115,30 +126,30 @@ def fused_stats(X: jnp.ndarray, rho: jnp.ndarray, beta: jnp.ndarray,
             "multichain fused_stats does not compose with a column "
             "window")
         C = wvec.shape[1]
-        margin = Xf @ wvec.astype(jnp.float32)            # (N, C)
+        margin = dot(Xf, wvec.astype(jnp.float32))        # (N, C)
         noise = seed_noise(seed, X.shape[0], C, epilogue)
         aug, weight, coef = epilogues.apply_epilogue(
             epilogue, margin, rho.astype(jnp.float32)[:, None],
             beta.astype(jnp.float32)[:, None], noise, eps, eps_ins)
         w = (weight if wmask is None
              else wmask.astype(jnp.float32)[:, None] * weight)
-        b = Xf.T @ coef                                   # (K, C)
-        S = jnp.stack([(Xf * w[:, c:c + 1]).T @ Xf for c in range(C)])
+        b = dot(Xf.T, coef)                               # (K, C)
+        S = jnp.stack([dot((Xf * w[:, c:c + 1]).T, Xf) for c in range(C)])
         return (margin, *aug, b, S)
     if seed is not None:
         noise = seed_noise(seed, X.shape[0], 1, epilogue)
-    margin = Xf @ wvec.astype(jnp.float32)
+    margin = dot(Xf, wvec.astype(jnp.float32))
     aug, weight, coef = epilogues.apply_epilogue(
         epilogue, margin, rho.astype(jnp.float32),
         beta.astype(jnp.float32), noise, eps, eps_ins)
     w = weight if wmask is None else wmask.astype(jnp.float32) * weight
-    b = Xf.T @ coef
+    b = dot(Xf.T, coef)
     if col_window is None:
-        return (margin, *aug, b, weighted_gram(X, w))
+        return (margin, *aug, b, weighted_gram(X, w, precision))
     start, blk = col_window
     Xc = jax.lax.dynamic_slice_in_dim(Xf, jnp.asarray(start, jnp.int32),
                                       blk, axis=1)
-    return (margin, *aug, b, (Xf * w[:, None]).T @ Xc)
+    return (margin, *aug, b, dot((Xf * w[:, None]).T, Xc))
 
 
 def nystrom_phi(X: jnp.ndarray, landmarks: jnp.ndarray, proj: jnp.ndarray,
@@ -155,10 +166,11 @@ def nystrom_phi(X: jnp.ndarray, landmarks: jnp.ndarray, proj: jnp.ndarray,
     if kind == "rbf":
         kmat = rbf_gram(Xf, landmarks, sigma)
     elif kind == "linear":
-        kmat = Xf @ landmarks.astype(jnp.float32).T
+        kmat = jnp.matmul(Xf, landmarks.astype(jnp.float32).T,
+                          precision=HIGHEST)
     else:
         raise ValueError(f"unknown kernel kind {kind!r}")
-    phi = kmat @ proj.astype(jnp.float32)
+    phi = jnp.matmul(kmat, proj.astype(jnp.float32), precision=HIGHEST)
     maskv = (jnp.ones((X.shape[0], 1), jnp.float32) if mask is None
              else mask.astype(jnp.float32)[:, None])
     if add_bias:
@@ -198,7 +210,7 @@ def nystrom_fused_stats(X: jnp.ndarray, landmarks: jnp.ndarray,
     phi = nystrom_phi(X, landmarks, proj, mask, sigma, kind, add_bias)
     return fused_stats(phi, rho, beta, wvec, mask, eps,
                        epilogue=epilogue, noise=noise, eps_ins=eps_ins,
-                       col_window=col_window, seed=seed)
+                       col_window=col_window, seed=seed, precision=HIGHEST)
 
 
 def rbf_gram(X1: jnp.ndarray, X2: jnp.ndarray, sigma: float) -> jnp.ndarray:
@@ -214,6 +226,6 @@ def rbf_gram(X1: jnp.ndarray, X2: jnp.ndarray, sigma: float) -> jnp.ndarray:
     X2f = X2.astype(jnp.float32)
     sq1 = jnp.sum(X1f * X1f, axis=-1, keepdims=True)
     sq2 = jnp.sum(X2f * X2f, axis=-1, keepdims=True)
-    d2 = sq1 - 2.0 * (X1f @ X2f.T) + sq2.T
+    d2 = sq1 - 2.0 * jnp.matmul(X1f, X2f.T, precision=HIGHEST) + sq2.T
     d2 = jnp.maximum(d2, 0.0)
     return jnp.exp(-d2 / (2.0 * sigma * sigma))

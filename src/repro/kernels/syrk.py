@@ -60,7 +60,7 @@ def tri_ij(t):
     return i, t - _tri(i)
 
 
-def _kernel(ij_ref, x_lhs_ref, w_ref, x_rhs_ref, out_ref):
+def _kernel(ij_ref, x_lhs_ref, w_ref, x_rhs_ref, out_ref, *, precision):
     del ij_ref  # consumed by the index maps only
     @pl.when(pl.program_id(1) == 0)
     def _init():
@@ -71,19 +71,20 @@ def _kernel(ij_ref, x_lhs_ref, w_ref, x_rhs_ref, out_ref):
     # (bk, bn) @ (bn, bk) on the MXU, fp32 accumulation.
     out_ref[...] += jax.lax.dot_general(
         xl, xr, dimension_numbers=(((0,), (0,)), ((), ())),
-        preferred_element_type=jnp.float32)
+        precision=precision, preferred_element_type=jnp.float32)
 
 
 @functools.partial(jax.jit, static_argnames=("block_n", "block_k",
-                                             "interpret"))
+                                             "precision", "interpret"))
 def syrk_tri(X: jnp.ndarray, w: jnp.ndarray, *,
-             block_n: int = 512, block_k: int = 256,
+             block_n: int = 512, block_k: int = 256, precision=None,
              interpret: bool = False) -> jnp.ndarray:
     """S = X^T diag(w) X via the triangle-blocked Pallas SYRK.
 
     X: (N, K); w: (N,). Returns the full symmetric (K, K) f32 matrix
     (mirrored from the computed lower block triangle). Inputs are
     zero-padded to block multiples; zero-weight rows are exact no-ops.
+    ``precision`` is the block dot's (None: the TPU's default).
     """
     N, K = X.shape
     bn = min(block_n, _round_up(N, 8))
@@ -110,7 +111,7 @@ def syrk_tri(X: jnp.ndarray, w: jnp.ndarray, *,
                                lambda t, n, ij: (ij[t, 0], ij[t, 1])),
     )
     out = pl.pallas_call(
-        _kernel,
+        functools.partial(_kernel, precision=precision),
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((Kp, Kp), jnp.float32),
         interpret=interpret,

@@ -308,6 +308,98 @@ def test_nystrom_fused_fits_accounting():
     assert not ops.nystrom_fused_fits(1024, 8192)
 
 
+def _dot_precisions(jaxpr):
+    """The precision of every dot_general in ``jaxpr`` and in the
+    jaxprs its equations hold (jit bodies, Pallas kernel bodies)."""
+    from jax.extend.core import ClosedJaxpr, Jaxpr
+
+    found = []
+    for eqn in jaxpr.eqns:
+        if eqn.primitive.name == "dot_general":
+            found.append(eqn.params["precision"])
+        for v in eqn.params.values():
+            for sub in v if isinstance(v, (tuple, list)) else (v,):
+                if isinstance(sub, ClosedJaxpr):
+                    found += _dot_precisions(sub.jaxpr)
+                elif isinstance(sub, Jaxpr):
+                    found += _dot_precisions(sub)
+    return found
+
+
+# Where the Nystrom statistic falls back to featurize-then-accumulate:
+# the LIN statistic kernel on phi, its split pair (E-step + SYRK), or,
+# past the landmark cap, the featurizing oracle too.
+FALLBACKS = {"kernel": (24, False), "split": (24, True),
+             "past_cap": (ops.NYSTROM_FUSED_MAX_M + 8, True)}
+
+
+def _forced_fallback(monkeypatch, route):
+    """(n, d, m) of a problem whose Nystrom statistic takes ``route``."""
+    m, split = FALLBACKS[route]
+    monkeypatch.setattr(ops, "nystrom_fused_fits", lambda *a, **k: False)
+    if split:
+        monkeypatch.setattr(ops, "fused_stats_fits", lambda *a, **k: False)
+    return 48, 6, m
+
+
+@pytest.mark.parametrize("route", sorted(FALLBACKS))
+def test_nystrom_fallback_runs_every_dot_at_highest(monkeypatch, route):
+    """The fallback computes the statistic the fused kernel does, in
+    float32: every dot it traces, the featurization's and the LIN
+    statistic's, asks for HIGHEST (on the TPU a dot at the default is
+    one bf16 pass, which gives another model)."""
+    import jax
+
+    n, d, m = _forced_fallback(monkeypatch, route)
+    X, L, proj, mask, y = _nystrom_problem(n, d, m, seed=6)
+    wv = np.random.default_rng(3).normal(size=m + 1).astype(np.float32)
+
+    def stats(*a):
+        return ops.nystrom_fused_stats(*a, sigma=1.0, add_bias=True,
+                                       eps=1e-6, backend="interpret")
+    args = [jnp.asarray(a) for a in (X, L, proj, y, y, wv, mask)]
+    found = _dot_precisions(jax.make_jaxpr(stats)(*args).jaxpr)
+    highest = (jax.lax.Precision.HIGHEST,) * 2
+    assert len(found) >= 4 and set(found) == {highest}, found
+    # the LIN statistic keeps the TPU's default
+    lin = jax.make_jaxpr(lambda *a: ops.fused_stats(
+        *a, backend="interpret"))(args[0], args[3], args[4],
+                                  jnp.asarray(wv[:d]), args[6])
+    assert set(_dot_precisions(lin.jaxpr)) == {None}
+
+
+@pytest.mark.parametrize("route", ["kernel", "split"])
+def test_nystrom_fallback_matches_fused_kernel(monkeypatch, route):
+    """Forced onto the fallback, the statistic equals the fused kernel's
+    at float32 tolerance: all four outputs, masked rows, phi bias."""
+    n, d, m = 96, 12, FALLBACKS[route][0]
+    X, L, proj, mask, y = _nystrom_problem(n, d, m, seed=8)
+    wv = np.random.default_rng(5).normal(size=m + 1).astype(np.float32)
+    args = [jnp.asarray(a) for a in (X, L, proj, y, y, wv, mask)]
+    kw = dict(sigma=1.2, add_bias=True, eps=1e-6, backend="interpret",
+              block_n=32)
+    assert ops.nystrom_fused_fits(m, d)
+    fused = ops.nystrom_fused_stats(*args, **kw)
+    _forced_fallback(monkeypatch, route)
+    got = ops.nystrom_fused_stats(*args, **kw)
+    for g, w_, name in zip(got, fused, ("margin", "gamma", "b", "S")):
+        g, w_ = np.asarray(g), np.asarray(w_)
+        np.testing.assert_allclose(
+            g, w_, rtol=1e-5, atol=1e-5 * max(1.0, np.abs(w_).max()),
+            err_msg=name)
+
+
+def test_nystrom_vmem_limit_is_the_least_that_holds_the_cap():
+    """The Nystrom kernels' raised scoped-VMEM limit is the least whole
+    8 MiB that holds the accounted working set at the landmark cap and
+    D = 256, where the budget stands."""
+    need = 4 * ops._nystrom_vmem_words(ops.NYSTROM_FUSED_MAX_M, 256, True,
+                                       256, True)
+    limit = ops._NYSTROM_VMEM_BUDGET
+    assert limit % (8 * 2 ** 20) == 0
+    assert need <= limit < need + 8 * 2 ** 20
+
+
 @pytest.mark.parametrize("n1,n2,k,sigma", [(64, 64, 16, 1.0),
                                            (100, 37, 8, 0.5),
                                            (129, 257, 33, 2.0)])

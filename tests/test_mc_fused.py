@@ -337,8 +337,9 @@ def test_nystrom_fused_fits_is_epilogue_aware():
     for m, d in ((256, 784), (1024, 256)):
         em = ops._nystrom_vmem_words(m, d, True, 256, True, "em_hinge")
         svr = ops._nystrom_vmem_words(m, d, True, 256, True, "mc_svr")
-        # mc_svr carries 4 noise + 1 extra aug per-row vectors over em
-        assert svr == em + 5 * 256, (m, d)
+        # mc_svr carries 4 noise + 1 extra aug per-row vectors over em,
+        # each a (256, 1) block padded to 128 lanes and double-buffered
+        assert svr == em + 2 * 5 * 128 * 256, (m, d)
         assert ops.nystrom_fused_fits(m, d, epilogue="em_hinge")
     assert not ops.nystrom_fused_fits(ops.NYSTROM_FUSED_MAX_M + 1, 16,
                                       epilogue="mc_svr")

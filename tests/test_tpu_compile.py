@@ -82,6 +82,38 @@ def test_fits_boundary_compiles(one_chip):
     assert _compiled_hlo(fn, *shapes).count("tpu_custom_call") == 1
 
 
+def test_fits_boundary_compiles_at_highest(one_chip):
+    """At HIGHEST (the Nystrom fallback's statistic) the widest K the
+    accounting admits compiles as ONE kernel under the default limit,
+    and one lane-tile more is refused by the accounting."""
+    hi = jax.lax.Precision.HIGHEST
+    K = max(k for k in range(128, 4096, 128)
+            if ops.fused_stats_fits(k, precision=hi))
+    assert not ops.fused_stats_fits(K + 128, precision=hi)
+
+    def sd(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    def fn(X, rho, beta, w):
+        return ops.fused_stats(X, rho, beta, w, precision=hi,
+                               backend="pallas")
+    hlo = _compiled_hlo(fn, sd((N, K)), sd((N,)), sd((N,)), sd((K,)))
+    assert hlo.count("tpu_custom_call") == 1
+
+
+@pytest.mark.parametrize("D", [54, 3072])
+def test_rbf_gram_compiles(one_chip, D):
+    """The exact-KRN Gram kernel, whose tile runs its cross dot at
+    HIGHEST: covtype's width, and the widest lane multiple it compiled
+    for at the default precision under the default limit."""
+    def sd(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    def fn(X1, X2):
+        return ops.rbf_gram(X1, X2, sigma=1.0, backend="pallas")
+    assert "tpu_custom_call" in _compiled_hlo(fn, sd((N, D)), sd((N, D)))
+
+
 @pytest.mark.parametrize("seed", [False, True])
 def test_nystrom_fused_stats_compiles(one_chip, seed):
     m, D = 512, 784
@@ -115,3 +147,64 @@ def test_nystrom_score_compiles(one_chip):
     hlo = _compiled_hlo(fn, sd((tile, D)), sd((m, D)), sd((m, m)),
                         sd((m + 1, 1)), sd((tile,)))
     assert "tpu_custom_call" in hlo
+
+
+def _nystrom_call(one_chip, m, D):
+    """(fn, shapes) for ops.nystrom_fused_stats, LIN-EM in phi-space
+    with the phi bias, over N rows of width D against m landmarks."""
+    def sd(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    def fn(X, lm, pj, rho, beta, w, mask):
+        return ops.nystrom_fused_stats(X, lm, pj, rho, beta, w, mask,
+                                       sigma=1.0, add_bias=True,
+                                       backend="pallas")
+    return fn, [sd((N, D)), sd((m, D)), sd((m, m)), sd((N,)), sd((N,)),
+                sd((m + 1,)), sd((N,))]
+
+
+def test_nystrom_kernels_compile_at_covtype(one_chip):
+    """covtype.binary's shape, m = ceil(sqrt(522,910)) = 724 landmarks
+    over D = 54: the fused statistic (every dot at HIGHEST) is one
+    kernel, and the score kernel, which shares the RBF tile, compiles
+    too."""
+    m, D = 724, 54
+    assert ops.nystrom_fused_fits(m, D)
+    fn, shapes = _nystrom_call(one_chip, m, D)
+    assert _compiled_hlo(fn, *shapes).count("tpu_custom_call") == 1
+
+    def sd(shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float32, sharding=one_chip)
+
+    def score(X, lm, pj, W, mask):
+        return ops.nystrom_score(X, lm, pj, W, mask, sigma=1.0,
+                                 add_bias=True, backend="pallas")
+    assert ops.nystrom_score_fits(m, D, 1, add_bias=True)
+    assert "tpu_custom_call" in _compiled_hlo(
+        score, sd((N, D)), sd((m, D)), sd((m, m)), sd((m + 1, 1)),
+        sd((N,)))
+
+
+@pytest.mark.parametrize("m", [724, ops.NYSTROM_FUSED_MAX_M])
+def test_nystrom_fits_boundary_compiles(one_chip, m):
+    """The widest D the VMEM accounting admits at m landmarks compiles
+    as ONE fused kernel under the kernels' raised limit, and one
+    lane-tile more is refused by the accounting."""
+    D = max(d for d in range(128, 8192, 128)
+            if ops.nystrom_fused_fits(m, d))
+    assert not ops.nystrom_fused_fits(m, D + 128)
+    fn, shapes = _nystrom_call(one_chip, m, D)
+    assert _compiled_hlo(fn, *shapes).count("tpu_custom_call") == 1
+
+
+@pytest.mark.parametrize("m,D,kernels", [
+    (512, 3200, 2),     # wide D: phi kernel, then the LIN kernel on phi
+    (724, 1920, 2),     # phi kernel, then the split pair (SYRK kernel)
+    (1100, 54, 1),      # past the landmark cap: XLA phi, split pair
+])
+def test_nystrom_fallback_compiles(one_chip, m, D, kernels):
+    """Past the fused budget the featurize-then-accumulate fallback,
+    every dot at HIGHEST, compiles on each of its routes."""
+    assert not ops.nystrom_fused_fits(m, D)
+    fn, shapes = _nystrom_call(one_chip, m, D)
+    assert _compiled_hlo(fn, *shapes).count("tpu_custom_call") == kernels
