@@ -5,11 +5,11 @@ explicit ``psum`` reductions over ``axes``; this module supplies the
 machinery around them:
 
   * ``upload_rows`` — place the caller's rows on the device(s) once and
-    build the model's X there (bias column, zero feature columns, zero
-    pad rows), row-sharded over the mesh's data axes exactly like the
-    paper assigns D^p to process p (padding rows are zeroed and masked
-    so statistics are exact). ``shard_rows`` places a matrix the host
-    has already built (the exact-KRN Gram).
+    build the model's X there (bias column, zero feature and lane
+    columns, zero pad rows), row-sharded over the mesh's data axes
+    exactly like the paper assigns D^p to process p (padding rows are
+    zeroed and masked so statistics are exact). ``shard_rows`` places a
+    matrix the host has already built (the exact-KRN Gram).
   * ``shard_wrap`` — wrap a step function in ``shard_map`` so each device
     runs the identical SPMD program (the paper's observation that all
     slaves perform the same operations — hence minimal sync latency — is
@@ -102,24 +102,40 @@ def shard_rows(mesh: Mesh, axes: Sequence[str], X: np.ndarray,
 def _augment_program(pad: int, bias: bool, fpad: int, sharding=None):
     """Jitted (X, mask) -> X with ``pad`` zero rows, then the bias column
     and ``fpad`` zero columns, appended. The bias column is the mask: 1
-    on real rows, 0 on pad rows. Row-local, so a row-sharded X is
-    augmented block by block with no communication."""
+    on real rows, 0 on pad rows. ``fpad`` counts the ``pad_features``
+    columns and the lane columns together: the program writes the
+    stored X at its final width, once a fit. Row-local, so a row-sharded
+    X is augmented block by block with no communication.
+
+    The bias column is selected into the padded X rather than
+    concatenated, so the mask is never laid out as an (N, 1) column
+    (tiled to 128 lanes, 128x its bytes on a TPU)."""
     def augment(X, mask):
         with jax.named_scope("augment"):
-            X = jnp.pad(X, ((0, pad), (0, 0)))
+            D = X.shape[1]
+            X = jnp.pad(X, ((0, pad), (0, bias + fpad)))
             if bias:
-                X = jnp.concatenate([X, mask[:, None]], axis=1)
-            return jnp.pad(X, ((0, 0), (0, fpad)))
+                col = jax.lax.broadcasted_iota(jnp.int32, X.shape, 1)
+                X = jnp.where(col == D, mask[:, None], X)
+            return X
     return jax.jit(augment, out_shardings=sharding)
 
 
 def upload_rows(X: np.ndarray, target: np.ndarray, mesh: Mesh | None,
                 axes: Sequence[str], *, bias: bool,
-                fpad: int = 0) -> SVMData:
+                fpad: int = 0, width: int | None = None) -> SVMData:
     """Send the caller's (N, D) rows to the device(s) once and build the
     model's X there: the bias column (``bias``), ``fpad`` zero feature
-    columns, and zero rows up to a multiple of (shards * 8), as
-    ``pad_rows`` pads.
+    columns, zero lane columns up to ``width`` columns, and zero rows up
+    to a multiple of (shards * 8), as ``pad_rows`` pads.
+
+    The model width is K = D + bias + fpad, the stored width ``width``
+    (K by default). Stored at the fused kernel's lane width
+    (``ops.fused_stats_width``), X is read by the kernel in place
+    rather than padded in a copy on every call; the weights stay K
+    wide, and the other readers of the stored X take its first K
+    columns. The ``pemsvm.upload`` span's ``lane_cols`` counts the lane
+    columns.
 
     Bitwise the array ``pad_rows`` + ``shard_rows`` build from a host
     copy with the columns appended, in the same row order, but the host
@@ -129,6 +145,7 @@ def upload_rows(X: np.ndarray, target: np.ndarray, mesh: Mesh | None,
     ``pemsvm.pad_rows`` span's ``host_bytes`` counts those copies.
     """
     N, D = X.shape
+    lane_cols = 0 if width is None else width - (D + bias + fpad)
     shards = num_shards(mesh, axes) if mesh is not None else 1
     chunk = shards * 8
     Np = ((N + chunk - 1) // chunk) * chunk
@@ -169,12 +186,14 @@ def upload_rows(X: np.ndarray, target: np.ndarray, mesh: Mesh | None,
             mask_d = jax.device_put(mask, row_sharding)
             dev_pad, out_sharding = 0, sharding
             up = sum(blocks[d].nbytes for d in devs)
-        span.set_metadata(bytes=up + tp.nbytes + mask.nbytes)
-        if not (dev_pad or bias or fpad):
+        span.set_metadata(bytes=up + tp.nbytes + mask.nbytes,
+                          lane_cols=lane_cols)
+        if not (dev_pad or bias or fpad or lane_cols):
             return SVMData(raw, tp_d, mask_d)
         # The raw rows go with this frame: their device memory is freed
         # once the program has read them.
-        program = _augment_program(dev_pad, bias, fpad, out_sharding)
+        program = _augment_program(dev_pad, bias, fpad + lane_cols,
+                                   out_sharding)
         return SVMData(program(raw, mask_d), tp_d, mask_d)
 
 

@@ -154,7 +154,9 @@ def mlt_step(data: SVMData, W: jnp.ndarray, key: jax.Array, *,
     X, labels, mask = data
     X = _maybe_featurize(X, mask, phi, phi_spec, backend)
     M = num_classes
-    Xf = X.astype(jnp.float32)
+    # A lane-padded model X (distributed.upload_rows): the scores read
+    # its first K columns, the statistic reads it whole.
+    Xf = X[:, :W.shape[1]].astype(jnp.float32)
     row0 = stats.shard_row_offset(X.shape[0], axes)
     col_window = (_k_block(W.shape[1], k_shard_axis)
                   if k_shard_axis is not None else None)

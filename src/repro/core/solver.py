@@ -30,6 +30,7 @@ from jax.profiler import TraceAnnotation
 from jax.sharding import Mesh, NamedSharding, PartitionSpec as P
 
 from repro.checkpoint import Checkpointer
+from repro.kernels import ops
 from repro.runtime.policy import FaultPolicy, StragglerError
 from repro.runtime.straggler import StepTimeMonitor
 
@@ -1461,9 +1462,16 @@ class PEMSVM:
         # D-wide rows are sharded/resident).
         fpad = ((-(K + cfg.add_bias)) % cfg.pad_features
                 if cfg.pad_features else 0)
-        data = distributed.upload_rows(X, target, self.mesh, self.data_axes,
-                                       bias=cfg.add_bias, fpad=fpad)
-        K = data.X.shape[1]
+        K = K + cfg.add_bias + fpad
+        # The statistic's X is stored at the width the fused kernel
+        # reads, so no kernel call pads a copy of it.
+        width = K if cfg.phi_spec is not None else ops.fused_stats_width(
+            K, backend=cfg.backend, n_chains=cfg.n_chains,
+            epilogue=(("em_" if cfg.algorithm == "EM" else "mc_")
+                      + ("svr" if cfg.task == "SVR" else "hinge")))
+        data = distributed.upload_rows(
+            X, target, self.mesh, self.data_axes, bias=cfg.add_bias,
+            fpad=fpad, width=width)
         with TraceAnnotation("pemsvm.upload") as span:
             prior = None
             if cfg.phi_spec is not None:
@@ -1578,8 +1586,6 @@ class PEMSVM:
         P = lam I + S (+ the config's relative jitter, mirroring
         ``stats.posterior_params``) and L = chol(P). Served std is
         ||phi U|| = sqrt(phi^T P^{-1} phi)."""
-        from repro.kernels import ops
-
         cfg = self.config
         if cfg.task == "MLT":
             raise NotImplementedError(

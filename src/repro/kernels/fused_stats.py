@@ -171,8 +171,13 @@ def fused_stats(X: jnp.ndarray, rho: jnp.ndarray, beta: jnp.ndarray,
     when a ``(col_start, col_blk)`` window is given (module docstring:
     static blk shapes the accumulator, traced start rides in SMEM).
 
-    X: (N, K); rho/beta/wmask: (N,); wvec: (K,); noise: ``noise_arity``
-    pre-drawn (N,) arrays for the MC epilogues (see ``epilogues.py``).
+    X: (N, K), or (N, Kp) with Kp = ``round_up(K, 128)``: an X stored
+    at the kernel's lane width (zero columns past K, as
+    ``distributed.upload_rows`` builds the model's X) is read in place
+    and only w is padded, where a K-wide X is padded to Kp on every
+    call (scope ``xpad``). rho/beta/wmask: (N,); wvec: (K,), its length
+    is the model width K; noise: ``noise_arity`` pre-drawn (N,) arrays
+    for the MC epilogues (see ``epilogues.py``).
     ``seed`` (a (4,) uint32 [k0, k1, row0, chain0] from
     ``rng.pack_seed``) switches the MC epilogues to the IN-KERNEL
     counter RNG: no noise operands enter the kernel at all, the (nu, u)
@@ -192,7 +197,8 @@ def fused_stats(X: jnp.ndarray, rho: jnp.ndarray, beta: jnp.ndarray,
     ``precision`` (a ``jax.lax.Precision``) is that of the margin, b and
     Sigma dots; None is the TPU's default, one bf16 pass.
     """
-    N, K = X.shape
+    N, Kx = X.shape
+    K = wvec.shape[0]
     multi = wvec.ndim == 2
     C = wvec.shape[1] if multi else 1
     windowed = col_blk is not None
@@ -219,16 +225,19 @@ def fused_stats(X: jnp.ndarray, rho: jnp.ndarray, beta: jnp.ndarray,
         wmask = jnp.ones((N,), jnp.float32)
     bn = min(block_n, _round_up(N, 8))
     Kp = _round_up(K, 128)
+    assert Kx in (K, Kp), (
+        f"X is {Kx} wide: the model width {K} or its lane width {Kp}")
     Np = _round_up(N, bn)
-    if (Np, Kp) != (N, K):
+    if (Np, Kp) != (N, Kx):
         with jax.named_scope("xpad"):
-            X = jnp.pad(X, ((0, Np - N), (0, Kp - K)))
+            X = jnp.pad(X, ((0, Np - N), (0, Kp - Kx)))
             rho = jnp.pad(rho, (0, Np - N))
             beta = jnp.pad(beta, (0, Np - N))
             wmask = jnp.pad(wmask, (0, Np - N))
-            wvec = (jnp.pad(wvec, ((0, Kp - K), (0, 0))) if multi
-                    else jnp.pad(wvec, (0, Kp - K)))
             noise = tuple(jnp.pad(z, (0, Np - N)) for z in noise)
+    if Kp != K:
+        wvec = (jnp.pad(wvec, ((0, Kp - K), (0, 0))) if multi
+                else jnp.pad(wvec, (0, Kp - K)))
 
     extra_specs: list = []
     extra_ops: tuple = ()

@@ -92,6 +92,11 @@ def _ru(x: int, m: int) -> int:
     return ((x + m - 1) // m) * m
 
 
+# The TPU's lane width: ``fused_stats`` reads X tiles of
+# ``round_up(K, LANE)`` columns, and the resident LIN path stores the
+# model's X at that width (``distributed.upload_rows``).
+LANE = 128
+
 # The LIN kernels do not raise their VMEM limit, so each runs under the
 # TPU compiler's default scoped limit (16 MiB on v5e); a working set
 # past it is refused at compile time (RESOURCE_EXHAUSTED in vmem). The
@@ -152,6 +157,20 @@ FUSED_STATS_MAX_K = max(k for k in range(128, 4096, 128)
                         if fused_stats_fits(k))
 
 
+def fused_stats_width(n_features: int, *, backend: str | None = None,
+                      epilogue: str = "em_hinge",
+                      n_chains: int = 1) -> int:
+    """The width to store an X of ``n_features`` columns at, so that
+    ``fused_stats`` reads it as it is: the lane width round_up(K, LANE)
+    where the call takes the full-width kernel, and K where it takes the
+    jnp oracle or the split fallback, which read K columns."""
+    if (_resolve(backend) == "ref"
+            or not fused_stats_fits(n_features, epilogue=epilogue,
+                                    n_chains=n_chains)):
+        return n_features
+    return _ru(n_features, LANE)
+
+
 def fused_stats(X: jnp.ndarray, rho: jnp.ndarray, beta: jnp.ndarray,
                 wvec: jnp.ndarray, wmask: jnp.ndarray | None = None,
                 noise: tuple | None = None, *,
@@ -186,7 +205,11 @@ def fused_stats(X: jnp.ndarray, rho: jnp.ndarray, beta: jnp.ndarray,
 
     ``precision`` (a ``jax.lax.Precision``) is that of every dot of the
     statistic, on each path: None, the TPU's default single bf16 pass,
-    for LIN; the Nystrom fallback asks for HIGHEST."""
+    for LIN; the Nystrom fallback asks for HIGHEST.
+
+    The kernel also takes X stored at its lane width, round_up(K, LANE)
+    columns with zeros past K, and reads it in place; the other routes
+    take a K-wide X (``fused_stats_width`` says which to store)."""
     backend = _resolve(backend)
     _check_noise(epilogue, noise, seed)
     multi = wvec.ndim == 2

@@ -16,13 +16,13 @@ from pathlib import Path
 import jax
 import numpy as np
 import pytest
-from jax.profiler import ProfileOptions, TraceAnnotation
+from jax.profiler import ProfileData, ProfileOptions, TraceAnnotation
 
 ROOT = Path(__file__).resolve().parents[1]
 sys.path[:0] = [str(ROOT), str(ROOT / "src")]
 
 from bench import tracefile  # noqa: E402
-from repro.core import PEMSVM, SVMConfig  # noqa: E402
+from repro.core import NystromSVM, PEMSVM, SVMConfig  # noqa: E402
 from repro.data import make_blobs  # noqa: E402
 
 MAX_ITERS, SCAN_CHUNK = 7, 3
@@ -129,3 +129,38 @@ def test_scan_fit_spans_four_device_mesh(tmp_path):
                        capture_output=True, text=True, timeout=600)
     assert p.returncode == 0, p.stderr[-3000:]
     check_scan_spans(json.loads(p.stdout.strip().splitlines()[-1]))
+
+
+def upload_lane_cols(log_dir, svm, X, y) -> list:
+    """The ``lane_cols`` arg of each ``pemsvm.upload`` span that has one,
+    in one profiled fit."""
+    opts = ProfileOptions()
+    opts.python_tracer_level = 0
+    opts.host_tracer_level = 2
+    jax.profiler.start_trace(str(log_dir), profiler_options=opts)
+    try:
+        svm.fit(X, y)
+    finally:
+        jax.profiler.stop_trace()
+    path = next(Path(log_dir).rglob("*.xplane.pb"))
+    pd = ProfileData.from_file(str(path))
+    return [dict(e.stats)["lane_cols"] for plane in pd.planes
+            for line in plane.lines for e in line.events
+            if e.name == "pemsvm.upload" and "lane_cols" in dict(e.stats)]
+
+
+@pytest.mark.parametrize("kw,lane_cols", [
+    ({"backend": "interpret"}, 128 - 13),
+    ({"backend": "interpret", "pad_features": 8}, 128 - 16),
+    ({"backend": "ref"}, 0),
+    ({"backend": "interpret", "formulation": "KRN"}, 0),
+])
+def test_upload_span_counts_lane_columns(tmp_path, kw, lane_cols):
+    """12 features and the bias column: the kernel backends store X at
+    the lane width, 128; the jnp oracles and the Nystrom route's raw
+    rows keep their width."""
+    X, y = make_blobs(512, 12, seed=3)
+    cfg = SVMConfig(max_iters=2, min_iters=2, tol=0.0, **kw)
+    svm = (NystromSVM(cfg, n_landmarks=16)
+           if cfg.formulation == "KRN" else PEMSVM(cfg))
+    assert upload_lane_cols(tmp_path, svm, X, y) == [lane_cols]

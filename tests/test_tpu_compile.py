@@ -7,9 +7,12 @@ compiler's own refusals (unsupported casts, unlowerable primitives,
 scoped-VMEM overflow) fail here instead of on the chip. The topology
 is described inside a fixture, never at import, so that parallel test
 workers collect the same tests and only the worker running this file
-loads the TPU compiler.
+loads the TPU compiler. The scan driver's chunk program is compiled
+the same way, at a chip's real row count, to read which ops of it write
+an array of X's size.
 """
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -208,3 +211,77 @@ def test_nystrom_fallback_compiles(one_chip, m, D, kernels):
     assert not ops.nystrom_fused_fits(m, D)
     fn, shapes = _nystrom_call(one_chip, m, D)
     assert _compiled_hlo(fn, *shapes).count("tpu_custom_call") == kernels
+
+
+ROWS = 524_288    # mnist8m-fit's rows on one chip
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%(\S+) .*\{$")
+_ARRAY_OP = re.compile(
+    r"^(?:ROOT )?%(\S+) = f32\[(\d+),(\d+)\]\{[^}]*\} ([\w\-]+)\(")
+
+
+def x_sized_writes(hlo: str, rows: int, widths) -> list[str]:
+    """The ops of a compiled module that write an f32 (rows, width)
+    array, for any width in ``widths``: every op outside a fused
+    computation, other than a parameter, a tuple element or a bitcast,
+    whose result is such an array."""
+    comps: dict[str, list[str]] = {}
+    fused, cur = set(), None
+    for line in hlo.splitlines():
+        m = _COMPUTATION.match(line)
+        if m:
+            cur = comps.setdefault(m[1], [])
+        elif line.startswith("}"):
+            cur = None
+        elif cur is not None:
+            cur.append(line.strip())
+            if " fusion(" in line:
+                fused.update(re.findall(r"calls=%([\w.\-]+)", line))
+    return [f"{m[4]} {m[1]}" for name, lines in comps.items()
+            if name not in fused for m in map(_ARRAY_OP.match, lines)
+            if m and int(m[2]) == rows and int(m[3]) in widths
+            and m[4] not in ("parameter", "get-tuple-element", "bitcast")]
+
+
+def _chunk_program(one_chip, task, K, width) -> str:
+    """The scan driver's chunk program (``solver._chunk_runner``) for a
+    resident LIN EM fit on one chip, with the model's X stored
+    ``width`` wide, compiled."""
+    from repro.core import SVMConfig, solver
+
+    def sd(shape, dtype=jnp.float32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    M = 10 if task == "MLT" else 2
+    cfg = SVMConfig(task=task, num_classes=M, backend="pallas",
+                    max_iters=4, scan_chunk=2)
+    runner = solver._chunk_runner(cfg, None, (), False, False)
+    state = (M, K) if task == "MLT" else (K,)
+    target = jnp.int32 if task == "MLT" else jnp.float32
+    data = solver.SVMData(sd((ROWS, width)), sd((ROWS,), target),
+                          sd((ROWS,)))
+    carry = (sd(state), sd(state), sd((), jnp.int32), sd((2,), jnp.uint32),
+             sd(()), sd((), jnp.int32), sd((), jnp.bool_),
+             sd((), jnp.int32))
+    return _compiled_hlo(runner, data, None, carry, sd((2,), jnp.int32),
+                         sd(()))
+
+
+@pytest.mark.parametrize("task,K", [("CLS", 801), ("MLT", 785)])
+def test_chunk_program_reads_lane_width_x_in_place(one_chip, task, K):
+    """dna's and mnist8m's widths with X stored at the lane width, as
+    ``distributed.upload_rows`` stores it: the kernel reads X in place,
+    and no op writes an array of X's size (no pad, copy or transpose of
+    X; MLT's score reads X[:, :K] through a bitcast)."""
+    hlo = _chunk_program(one_chip, task, K, 896)
+    assert "tpu_custom_call" in hlo
+    assert x_sized_writes(hlo, ROWS, {896, K}) == []
+
+
+def test_chunk_program_copies_a_k_wide_x(one_chip):
+    """X stored at mnist8m's K = 785: the compiler lays the parameter
+    out column-major, copies it row-major and pads it to 896 on every
+    kernel call. The check above has to see those copies."""
+    hlo = _chunk_program(one_chip, "MLT", 785, 785)
+    writes = x_sized_writes(hlo, ROWS, {896, 785})
+    assert any(w.startswith("copy ") for w in writes), writes
+    assert any(w.startswith("pad ") for w in writes), writes
