@@ -84,18 +84,18 @@ def krn_step(data: SVMData, K_prior: jnp.ndarray, omega: jnp.ndarray,
     margin, gamma, b, S = ops.fused_stats(K_rows, y, y, omega, mask,
                                           noise, epilogue=epilogue,
                                           eps=eps, backend=backend)
-    S, b = stats.reduce_stats(S, b, axes, triangle=triangle,
-                              reduce_dtype=reduce_dtype, live=live)
-
-    L, mu = stats.posterior_params(S, b, lam, prior_precision=K_prior,
-                                   jitter=jitter)
-    omega_new = mu if mode == "EM" else stats.draw_weight(key, L, mu)
+    S, b = stats.reduce(S, b, axes, triangle=triangle,
+                        reduce_dtype=reduce_dtype, live=live)
+    omega_new = stats.m_step(S, b, key, mode=mode, lam=lam, jitter=jitter,
+                             prior_precision=K_prior)
 
     K_omega = K_prior @ omega_new
     obj = objective.kernel_reg(omega_new, K_omega, lam) + stats.preduce(
         objective.hinge_obj_terms(margin, y, mask), axes, live)
     return omega_new, {"objective": obj,
-                       "gamma_mean": stats.masked_mean(gamma, mask, axes, live)}
+                       "gamma_mean": stats.masked_mean(
+                           jnp.sum(gamma * mask), jnp.sum(mask), axes,
+                           live)}
 
 
 def decision_function(omega: jnp.ndarray, X_train: jnp.ndarray,

@@ -1,7 +1,7 @@
 """LIN-{EM,MC}-CLS: linear binary SVM via data augmentation (paper Sec 2, 4).
 
 One iteration over a *local* data shard (rows of other shards live on other
-devices; reductions go through ``stats.reduce_stats``):
+devices; reductions go through ``stats.reduce``):
 
   E-step   gamma_d from the residual y_d - w^T x_d      O(NK/P)
   stats    Sigma^p = X^T diag(1/gamma) X                O(NK^2/P)   <- Pallas
@@ -148,10 +148,6 @@ def accumulate_stats(X: jnp.ndarray, rho: jnp.ndarray, beta: jnp.ndarray,
     return margin, gamma, S, b
 
 
-# Back-compat name: pre-streaming callers knew this as local_stats.
-local_stats = accumulate_stats
-
-
 def _k_block(width: int, axis_name: str):
     """(start, blk) Sigma column window of the width-K statistic for
     this model-axis shard — ``blk`` is static, ``start`` traced
@@ -176,29 +172,55 @@ def _k_block(width: int, axis_name: str):
     return jax.lax.axis_index(axis_name) * blk, blk
 
 
-def chain_keys(key: jax.Array, chain0: int, n_chains: int) -> jax.Array:
-    """Per-chain weight-draw keys: ``fold_in(key, chain0 + c)``.
+def resident_step(chunk_stats, data: SVMData, w: jnp.ndarray,
+                  key: jax.Array, *, mode: str, lam: float, jitter: float,
+                  axes: Sequence[str], triangle: bool,
+                  k_shard_axis: str | None, reduce_dtype: str | None,
+                  live: jnp.ndarray | None, rng: str, n_chains: int,
+                  chain0: int, **stat_kw):
+    """One in-memory LIN iteration of CLS or SVR, composed from the
+    task's chunk statistic ``chunk_stats`` (``cls_chunk_stats`` /
+    ``svr.svr_chunk_stats``) — the same E-step the stream driver sums —
+    over this shard's rows, then ``stats.reduce``, ``stats.m_step``,
+    and the aux from the psummed scalars. Returns (w_new, aux).
 
-    Under the counter rng modes EVERY weight draw is chain-keyed (even
-    n_chains = 1), so chain c's draw depends only on (iteration key,
-    absolute chain id) — never on how many chains ride the same fit."""
-    ids = jnp.asarray(chain0, jnp.int32) + jnp.arange(n_chains,
-                                                      dtype=jnp.int32)
-    return jax.vmap(jax.random.fold_in, in_axes=(None, 0))(key, ids)
-
-
-def multichain_draw(key: jax.Array, S: jnp.ndarray, b: jnp.ndarray,
-                    lam: float, jitter: float, chain0: int):
-    """Per-chain posterior solves + chain-keyed Gibbs weight draws.
-
-    ``S`` (C, K, K), ``b`` (K, C) -> (C, K) draws: C independent
-    Cholesky factorizations of lam*I + S_c and
-    ``draw_weight(fold_in(key, chain0 + c), L_c, mu_c)``."""
-    C = S.shape[0]
-    L, mu = jax.vmap(
-        lambda Sc, bc: stats.posterior_params(Sc, bc, lam, jitter=jitter)
-    )(S, b.T)
-    return jax.vmap(stats.draw_weight)(chain_keys(key, chain0, C), L, mu)
+    The statistic's scalars are chain sums; here each is psummed first
+    and averaged over chains after (the stream driver averages each
+    chunk before its sum instead). ``loss`` makes the objective; every
+    other ``<v>_sum`` is reported as ``<v>_mean`` over the ``mask_sum``
+    valid rows (``stats.masked_mean``), and any other scalar (``n_sv``)
+    as its chain mean."""
+    # Rowwise MC draws are keyed by global row index, so shards need no
+    # per-shard key folds — the row offset decorrelates them and keeps
+    # the chain identical to the single-device and streaming drivers.
+    row0 = stats.shard_row_offset(data.X.shape[0], axes)
+    # 2-D (data x model) statistic: this model-shard computes only its
+    # Sigma column block — INSIDE the same single-stream fused kernel
+    # (col_window), for EM and MC, X- and phi-space alike; the packed
+    # psum + block all-gather rebuild the full Sigma (stats.reduce).
+    col_window = (_k_block(w.shape[0], k_shard_axis)
+                  if k_shard_axis is not None else None)
+    sums = chunk_stats(data, w, key, row0, mode=mode, rng=rng,
+                       n_chains=n_chains, chain0=chain0,
+                       col_window=col_window, **stat_kw)
+    S, b = stats.reduce(sums.pop("S"), sums.pop("b"), axes,
+                        k_shard_axis=k_shard_axis, triangle=triangle,
+                        reduce_dtype=reduce_dtype, live=live)
+    w_new = stats.m_step(S, b, key, mode=mode, lam=lam, jitter=jitter,
+                         rng=rng, chain0=chain0, n_chains=n_chains)
+    count = sums.pop("mask_sum")
+    aux = {"objective": (
+        stats.chain_mean(objective.l2_reg(w_new, lam), n_chains)
+        + stats.chain_mean(stats.preduce(sums.pop("loss"), axes, live),
+                           n_chains))}
+    for k, v in sums.items():
+        if k.endswith("_sum"):
+            aux[k[:-len("_sum")] + "_mean"] = stats.masked_mean(
+                v, count, axes, live)
+        else:
+            aux[k] = stats.chain_mean(stats.preduce(v, axes, live),
+                                      n_chains)
+    return w_new, aux
 
 
 @partial(jax.jit, static_argnames=("mode", "lam", "eps", "jitter", "axes",
@@ -214,7 +236,8 @@ def cls_step(data: SVMData, w: jnp.ndarray, key: jax.Array, *,
              phi=None, phi_spec: PhiSpec | None = None,
              live: jnp.ndarray | None = None,
              rng: str = "host", n_chains: int = 1, chain0: int = 0):
-    """One LIN-*-CLS iteration. Returns (w_new, aux dict).
+    """One LIN-*-CLS iteration. Returns (w_new, aux dict) with aux keys
+    ``objective``, ``gamma_mean`` and ``n_sv`` (``resident_step``).
 
     ``live`` (this shard's liveness weight) renormalizes every reduction
     around dropped replicas — see ``stats.preduce``; all-ones is bitwise
@@ -225,58 +248,12 @@ def cls_step(data: SVMData, w: jnp.ndarray, key: jax.Array, *,
     the weight state CHAIN-MAJOR as (C, K): the statistic runs all C
     chains over one X stream, the C posterior solves are vmapped, and
     the reported objective/diagnostics are cross-chain means."""
-    X, y, mask = data
-    multi = n_chains > 1
-    # Rowwise MC draws are keyed by global row index, so shards need no
-    # per-shard key folds — the row offset decorrelates them and keeps
-    # the chain identical to the single-device and streaming drivers.
-    row0 = stats.shard_row_offset(X.shape[0], axes)
-
-    # 2-D (data x model) statistic: this model-shard computes only its
-    # Sigma column block — INSIDE the same single-stream fused kernel
-    # (col_window), for EM and MC, X- and phi-space alike; the packed
-    # psum + block all-gather rebuild the full Sigma (stats.reduce_kshard).
-    col_window = (_k_block(w.shape[0], k_shard_axis)
-                  if k_shard_axis is not None else None)
-    margin, gamma, S, b = accumulate_stats(
-        X, y, y, w.T if multi else w, mode=mode, key=key, eps=eps,
-        backend=backend, row0=row0, phi=phi, phi_spec=phi_spec, mask=mask,
-        col_window=col_window, rng=rng, chain0=chain0)
-    if k_shard_axis is None:
-        S, b = stats.reduce_stats(S, b, axes, triangle=triangle,
-                                  reduce_dtype=reduce_dtype, live=live)
-    else:
-        S, b = stats.reduce_kshard(S, b, axes, k_shard_axis,
-                                   reduce_dtype=reduce_dtype, live=live)
-
-    if multi:
-        with jax.named_scope("mstep"):
-            w_new = multichain_draw(key, S, b, lam, jitter, chain0)
-        maskc = jnp.broadcast_to(mask[:, None], margin.shape)
-        obj = objective.l2_reg(w_new, lam) / n_chains + stats.preduce(
-            objective.hinge_obj_terms(margin, y[:, None], maskc),
-            axes, live) / n_chains
-        n_sv = stats.preduce(jnp.sum(maskc * (gamma <= 2.0 * eps)),
-                             axes, live) / n_chains
-        gamma_mean = stats.masked_mean(gamma, maskc, axes, live)
-    else:
-        with jax.named_scope("mstep"):
-            L, mu = stats.posterior_params(S, b, lam, jitter=jitter)
-            if mode == "EM":
-                w_new = mu
-            elif rng == "host":
-                w_new = stats.draw_weight(key, L, mu)
-            else:
-                w_new = stats.draw_weight(chain_keys(key, chain0, 1)[0],
-                                          L, mu)
-        obj = objective.l2_reg(w_new, lam) + stats.preduce(
-            objective.hinge_obj_terms(margin, y, mask), axes, live)
-        n_sv = stats.preduce(jnp.sum(mask * (gamma <= 2.0 * eps)),
-                             axes, live)
-        gamma_mean = stats.masked_mean(gamma, mask, axes, live)
-    return w_new, {"objective": obj,
-                   "gamma_mean": gamma_mean,
-                   "n_sv": n_sv}
+    return resident_step(
+        cls_chunk_stats, data, w, key, mode=mode, lam=lam, jitter=jitter,
+        axes=axes, triangle=triangle, k_shard_axis=k_shard_axis,
+        reduce_dtype=reduce_dtype, live=live, rng=rng, n_chains=n_chains,
+        chain0=chain0, eps=eps, backend=backend, phi=phi,
+        phi_spec=phi_spec)
 
 
 def cls_chunk_stats(chunk: SVMData, w: jnp.ndarray, key: jax.Array,
@@ -284,44 +261,40 @@ def cls_chunk_stats(chunk: SVMData, w: jnp.ndarray, key: jax.Array,
                     backend: str | None, phi=None,
                     phi_spec: PhiSpec | None = None,
                     rng: str = "host", n_chains: int = 1,
-                    chain0: int = 0) -> dict:
-    """Streaming E-step body for CLS: one chunk's additive contributions.
+                    chain0: int = 0,
+                    col_window: tuple | None = None) -> dict:
+    """THE CLS E-step over one row block: its additive contributions.
 
-    Every field is an exact sum over the chunk's valid rows, so the
+    Every field is an exact sum over the block's valid rows, so the
     stream driver tree-sums these dicts across chunks and lands on the
-    same (Sigma, b, loss, aux) the in-memory step computes in one shot
-    (padded rows contribute zero by the layout convention; in phi-space
-    the mask enforces it — see ``accumulate_stats``).
+    same (Sigma, b, loss, aux) the in-memory step (``resident_step``,
+    which runs this over its whole shard) computes in one shot (padded
+    rows contribute zero by the layout convention; in phi-space the
+    mask enforces it — see ``accumulate_stats``). ``col_window`` is the
+    k-shard column block (``accumulate_stats``).
 
-    Multichain (counter rng) chunks carry S (C, K, K) / b (K, C) and
-    chain-MEAN scalar diagnostics; the counter keying makes the draws —
-    and therefore the whole chain — invariant to the chunk grid, which
-    is what the elastic mid-pass resume test pins bitwise.
+    Multichain (counter rng) blocks carry S (C, K, K) / b (K, C), and
+    each scalar sums over (row, chain) pairs — ``mask_sum`` too; each
+    driver takes the chain mean (``stats.chain_mean``) in its own float
+    order. The counter keying makes the draws — and therefore the whole
+    chain — invariant to the chunk grid, which is what the elastic
+    mid-pass resume test pins bitwise.
     """
     X, y, mask = chunk
     multi = n_chains > 1
     margin, gamma, S, b = accumulate_stats(
         X, y, y, w.T if multi else w, mode=mode, key=key, eps=eps,
         backend=backend, row0=row0, phi=phi, phi_spec=phi_spec, mask=mask,
-        rng=rng, chain0=chain0)
+        col_window=col_window, rng=rng, chain0=chain0)
     if multi:
-        maskc = jnp.broadcast_to(mask[:, None], margin.shape)
-        return {
-            "S": S,
-            "b": b,
-            "loss": objective.hinge_obj_terms(margin, y[:, None],
-                                              maskc) / n_chains,
-            "gamma_sum": jnp.sum(gamma * maskc) / n_chains,
-            "mask_sum": jnp.sum(mask),
-            "n_sv": jnp.sum(maskc * (gamma <= 2.0 * eps)) / n_chains,
-        }
+        y, mask = y[:, None], jnp.broadcast_to(mask[:, None], margin.shape)
     return {
         "S": S,
         "b": b,
         "loss": objective.hinge_obj_terms(margin, y, mask),
+        "n_sv": jnp.sum(mask * (gamma <= 2.0 * eps)),
         "gamma_sum": jnp.sum(gamma * mask),
         "mask_sum": jnp.sum(mask),
-        "n_sv": jnp.sum(mask * (gamma <= 2.0 * eps)),
     }
 
 

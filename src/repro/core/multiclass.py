@@ -29,7 +29,9 @@ because the incrementally-maintained F's columns are exactly X w_c for
 each class c at its current value — trading O(NKM^2) extra margin
 FLOPs per sweep (each of the M class passes rebuilds the (N, M) score
 matrix) for never holding N rows at once; Sigma's O(NK^2 M) still
-dominates while M < K.
+dominates while M < K. So the two drivers keep their own class E-step,
+and share the class statistic (``accumulate_stats``), the reduce
+(``stats.reduce``) and the M-step (``stats.m_step``).
 """
 from __future__ import annotations
 
@@ -168,25 +170,16 @@ def mlt_step(data: SVMData, W: jnp.ndarray, key: jax.Array, *,
         W, F = carry
         rho, beta = _rho_beta(F, labels, y, M)
         # Padding rows: X-row == 0 => margin 0 and zero stats contribution.
-        _, gamma, S, b = accumulate_stats(
-            X, rho, beta, W[y], mode=mode,
-            key=jax.random.fold_in(key, y), eps=eps, backend=backend,
-            row0=row0, col_window=col_window, rng=rng, chain0=chain0)
-        if k_shard_axis is None:
-            S, b = stats.reduce_stats(S, b, axes, triangle=triangle,
-                                      reduce_dtype=reduce_dtype, live=live)
-        else:
-            S, b = stats.reduce_kshard(S, b, axes, k_shard_axis,
-                                       reduce_dtype=reduce_dtype, live=live)
-        with jax.named_scope("mstep"):
-            L, mu = stats.posterior_params(S, b, lam, jitter=jitter)
-            if mode == "EM":
-                w_new = mu
-            else:
-                ky = jax.random.fold_in(key, y)
-                if rng != "host":
-                    ky = jax.random.fold_in(ky, chain0)
-                w_new = stats.draw_weight(ky, L, mu)
+        ky = jax.random.fold_in(key, y)
+        _, _, S, b = accumulate_stats(
+            X, rho, beta, W[y], mode=mode, key=ky, eps=eps,
+            backend=backend, row0=row0, col_window=col_window, rng=rng,
+            chain0=chain0)
+        S, b = stats.reduce(S, b, axes, k_shard_axis=k_shard_axis,
+                            triangle=triangle, reduce_dtype=reduce_dtype,
+                            live=live)
+        w_new = stats.m_step(S, b, ky, mode=mode, lam=lam, jitter=jitter,
+                             rng=rng, chain0=chain0)
         W = W.at[y].set(w_new)
         with jax.named_scope("score"):
             F = F.at[:, y].set(Xf @ w_new)

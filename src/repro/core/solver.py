@@ -231,64 +231,40 @@ def _build_step_fn(cfg: SVMConfig, mesh: Mesh | None,
                   jitter=cfg.jitter, axes=tuple(axes),
                   triangle=cfg.triangle_reduce, backend=cfg.backend,
                   reduce_dtype=cfg.reduce_dtype)
-    if cfg.formulation != "KRN":
-        # Counter-rng plumbing (LIN steps only; KRN keeps the legacy
-        # host draw and the config rejects rng != 'host' there).
-        common.update(rng=cfg.rng, chain0=cfg.chain0)
-    chains = dict(n_chains=cfg.n_chains)
 
     def _live(rest):
         return rest[0] if rest else None
 
     if cfg.formulation == "KRN":
+        # The exact-Gram step keeps the host draw; the config rejects
+        # rng != 'host' there.
         def step(data, prior, state, key, *rest):
             return krn.krn_step(data, prior, state, key,
                                 live=_live(rest), **common)
-    elif cfg.phi_spec is not None:
-        # Nystrom phi-space steps: the featurizer arrays (landmarks,
-        # K_mm^{-1/2}) ride the replicated ``prior`` slot — the same
-        # plumbing the exact-KRN Gram prior uses — so the scan driver
-        # and shard_wrap carry them without a second mechanism.
-        if cfg.task == "CLS":
-            def step(data, prior, state, key, *rest):
-                return linear.cls_step(data, state, key,
-                                       k_shard_axis=cfg.k_shard_axis,
-                                       phi=prior, phi_spec=cfg.phi_spec,
-                                       live=_live(rest), **common,
-                                       **chains)
-        elif cfg.task == "SVR":
-            def step(data, prior, state, key, *rest):
-                return svr.svr_step(data, state, key,
-                                    eps_ins=cfg.eps_ins, phi=prior,
-                                    k_shard_axis=cfg.k_shard_axis,
-                                    phi_spec=cfg.phi_spec,
-                                    live=_live(rest), **common,
-                                    **chains)
-        else:
-            def step(data, prior, state, key, *rest):
-                return multiclass.mlt_step(data, state, key,
-                                           num_classes=cfg.num_classes,
-                                           k_shard_axis=cfg.k_shard_axis,
-                                           phi=prior,
-                                           phi_spec=cfg.phi_spec,
-                                           live=_live(rest), **common)
-    elif cfg.task == "CLS":
-        def step(data, state, key, *rest):
-            return linear.cls_step(data, state, key,
-                                   k_shard_axis=cfg.k_shard_axis,
-                                   live=_live(rest), **common, **chains)
-    elif cfg.task == "SVR":
-        def step(data, state, key, *rest):
-            return svr.svr_step(data, state, key,
-                                k_shard_axis=cfg.k_shard_axis,
-                                eps_ins=cfg.eps_ins,
-                                live=_live(rest), **common, **chains)
     else:
-        def step(data, state, key, *rest):
-            return multiclass.mlt_step(data, state, key,
-                                       k_shard_axis=cfg.k_shard_axis,
-                                       num_classes=cfg.num_classes,
-                                       live=_live(rest), **common)
+        task_step, task_kw = {
+            "CLS": (linear.cls_step, dict(n_chains=cfg.n_chains)),
+            "SVR": (svr.svr_step, dict(eps_ins=cfg.eps_ins,
+                                       n_chains=cfg.n_chains)),
+            "MLT": (multiclass.mlt_step,
+                    dict(num_classes=cfg.num_classes)),
+        }[cfg.task]
+        common.update(task_kw, rng=cfg.rng, chain0=cfg.chain0,
+                      k_shard_axis=cfg.k_shard_axis,
+                      phi_spec=cfg.phi_spec)
+        if cfg.phi_spec is None:
+            def step(data, state, key, *rest):
+                return task_step(data, state, key, live=_live(rest),
+                                 **common)
+        else:
+            # Nystrom phi-space: the featurizer arrays (landmarks,
+            # K_mm^{-1/2}) ride the replicated ``prior`` slot — the
+            # same plumbing the exact-KRN Gram prior uses — so the scan
+            # driver and shard_wrap carry them without a second
+            # mechanism.
+            def step(data, prior, state, key, *rest):
+                return task_step(data, state, key, phi=prior,
+                                 live=_live(rest), **common)
 
     if mesh is None:
         return step
@@ -368,8 +344,9 @@ def _stream_fns(cfg: SVMConfig):
     caches; shapes fixed by chunk_rows mean ONE trace per dataset width.
 
     Contract: ``chunk`` maps one (chunk_rows, K) block to a dict of
-    row-additive contributions; ``add`` tree-sums them; ``mstep`` is the
-    unchanged replicated posterior solve/draw on the summed statistics.
+    row-additive contributions; ``add`` tree-sums them; ``mstep`` is
+    ``stats.m_step``, the resident steps' M-step, on the summed
+    statistics.
     For MLT, ``chunk``/``mstep`` additionally take the traced class
     index (one solve per class per sweep) and ``obj`` scores the
     end-of-sweep W on one block.
@@ -381,6 +358,8 @@ def _stream_fns(cfg: SVMConfig):
     """
     common = dict(mode=cfg.algorithm, eps=cfg.eps, backend=cfg.backend,
                   phi_spec=cfg.phi_spec)
+    mkw = dict(mode=cfg.algorithm, lam=cfg.lam, jitter=cfg.jitter,
+               rng=cfg.rng, chain0=cfg.chain0)
     add = jax.jit(functools.partial(jax.tree_util.tree_map, jnp.add))
 
     if cfg.task == "MLT":
@@ -393,16 +372,8 @@ def _stream_fns(cfg: SVMConfig):
 
         @jax.jit
         def mstep(W, S, b, key, y_cls):
-            L, mu = stats.posterior_params(S, b, cfg.lam,
-                                           jitter=cfg.jitter)
-            if cfg.algorithm == "EM":
-                w_new = mu
-            else:
-                ky = jax.random.fold_in(key, y_cls)
-                if cfg.rng != "host":
-                    ky = jax.random.fold_in(ky, cfg.chain0)
-                w_new = stats.draw_weight(ky, L, mu)
-            return W.at[y_cls].set(w_new)
+            return W.at[y_cls].set(stats.m_step(
+                S, b, jax.random.fold_in(key, y_cls), **mkw))
 
         @jax.jit
         def obj(data, W, phi):
@@ -417,37 +388,24 @@ def _stream_fns(cfg: SVMConfig):
                     obj_total=obj_total)
 
     chains = dict(rng=cfg.rng, n_chains=cfg.n_chains, chain0=cfg.chain0)
-    if cfg.task == "SVR":
-        @jax.jit
-        def chunk(data, w, key, row0, phi):
-            return svr.svr_chunk_stats(data, w, key, row0,
-                                       eps_ins=cfg.eps_ins, phi=phi,
-                                       **common, **chains)
-    else:
-        @jax.jit
-        def chunk(data, w, key, row0, phi):
-            return linear.cls_chunk_stats(data, w, key, row0, phi=phi,
-                                          **common, **chains)
+    task_chunk = (functools.partial(svr.svr_chunk_stats, eps_ins=cfg.eps_ins)
+                  if cfg.task == "SVR" else linear.cls_chunk_stats)
+
+    @jax.jit
+    def chunk(data, w, key, row0, phi):
+        # Each chunk's chain sums become chain means before the chunks
+        # are summed (the resident step averages after its psum).
+        return {k: v if k in ("S", "b") else stats.chain_mean(
+                    v, cfg.n_chains)
+                for k, v in task_chunk(data, w, key, row0, phi=phi,
+                                       **common, **chains).items()}
 
     @jax.jit
     def mstep(S, b, loss_sum, key):
-        if cfg.n_chains > 1:
-            # Per-chain posterior solves + chain-keyed draws; the chunk
-            # loss is already the cross-chain mean, so only l2 scales.
-            w_new = linear.multichain_draw(key, S, b, cfg.lam,
-                                           cfg.jitter, cfg.chain0)
-            obj = (objective.l2_reg(w_new, cfg.lam) / cfg.n_chains
-                   + loss_sum)
-            return w_new, obj
-        L, mu = stats.posterior_params(S, b, cfg.lam, jitter=cfg.jitter)
-        if cfg.algorithm == "EM":
-            w_new = mu
-        elif cfg.rng == "host":
-            w_new = stats.draw_weight(key, L, mu)
-        else:
-            w_new = stats.draw_weight(
-                linear.chain_keys(key, cfg.chain0, 1)[0], L, mu)
-        return w_new, objective.l2_reg(w_new, cfg.lam) + loss_sum
+        # The chunk loss is already the cross-chain mean.
+        w_new = stats.m_step(S, b, key, n_chains=cfg.n_chains, **mkw)
+        return w_new, (stats.chain_mean(objective.l2_reg(w_new, cfg.lam),
+                                        cfg.n_chains) + loss_sum)
 
     return dict(chunk=chunk, add=add, mstep=mstep)
 

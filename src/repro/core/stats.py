@@ -82,15 +82,16 @@ def preduce(x: jnp.ndarray, axes: Sequence[str] | None,
         return num * scale.astype(num.dtype)
 
 
-def masked_mean(x: jnp.ndarray, mask: jnp.ndarray,
+def masked_mean(total: jnp.ndarray, count: jnp.ndarray,
                 axes: Sequence[str] | None,
                 live: jnp.ndarray | None = None) -> jnp.ndarray:
-    """Globally-reduced mean of x over valid rows (diagnostics). The
+    """Globally-reduced mean over valid rows (diagnostics) from this
+    shard's masked row sum ``total`` and its valid-row ``count``. The
     ``live`` renormalization factors cancel between num and den, so the
     dropped-shard mean is the mean over surviving rows — the right
     diagnostic."""
-    num = preduce(jnp.sum(x * mask), axes, live)
-    den = preduce(jnp.sum(mask), axes, live)
+    num = preduce(total, axes, live)
+    den = preduce(count, axes, live)
     return num / jnp.maximum(den, 1.0)
 
 
@@ -193,6 +194,21 @@ def reduce_kshard(S_blk: jnp.ndarray, b: jnp.ndarray,
     return S, b
 
 
+def reduce(S: jnp.ndarray, b: jnp.ndarray, axes: Sequence[str] | None,
+           *, k_shard_axis: str | None = None, triangle: bool = True,
+           reduce_dtype: str | None = None,
+           live: jnp.ndarray | None = None
+           ) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """THE step's all-reduce of (Sigma^p, mu^p): the 1-D data-parallel
+    ``reduce_stats``, or ``reduce_kshard`` when the statistic is the
+    2-D (data x model) column block of ``k_shard_axis``."""
+    if k_shard_axis is None:
+        return reduce_stats(S, b, axes, triangle=triangle,
+                            reduce_dtype=reduce_dtype, live=live)
+    return reduce_kshard(S, b, axes, k_shard_axis,
+                         reduce_dtype=reduce_dtype, live=live)
+
+
 def posterior_params(S: jnp.ndarray, b: jnp.ndarray, lam: float,
                      prior_precision: jnp.ndarray | None = None,
                      jitter: float = 0.0):
@@ -222,6 +238,56 @@ def draw_weight(key: jax.Array, L: jnp.ndarray, mu: jnp.ndarray) -> jnp.ndarray:
     """MC draw w ~ N(mu, P^{-1}) via w = mu + L^{-T} z (paper Eq. 4)."""
     z = jax.random.normal(key, mu.shape, dtype=mu.dtype)
     return mu + jax.scipy.linalg.solve_triangular(L.T, z, lower=False)
+
+
+def chain_keys(key: jax.Array, chain0: int, n_chains: int) -> jax.Array:
+    """Per-chain weight-draw keys: ``fold_in(key, chain0 + c)``.
+
+    Under the counter rng modes EVERY weight draw is chain-keyed (even
+    n_chains = 1), so chain c's draw depends only on (iteration key,
+    absolute chain id) — never on how many chains ride the same fit."""
+    ids = jnp.asarray(chain0, jnp.int32) + jnp.arange(n_chains,
+                                                      dtype=jnp.int32)
+    return jax.vmap(jax.random.fold_in, in_axes=(None, 0))(key, ids)
+
+
+def m_step(S: jnp.ndarray, b: jnp.ndarray, key: jax.Array, *, mode: str,
+           lam: float, jitter: float, rng: str = "host", chain0: int = 0,
+           n_chains: int = 1,
+           prior_precision: jnp.ndarray | None = None) -> jnp.ndarray:
+    """THE M-step every step and driver runs on its reduced statistic:
+    the posterior solve, then the EM mean (``mode='EM'``) or one MC
+    Gibbs draw of the weight.
+
+    The draw's key rule lives here alone: ``key`` as given under
+    ``rng='host'``, ``fold_in(key, chain0 + c)`` for chain c under the
+    counter modes (the MLT class sweep passes its per-class key
+    ``fold_in(key, y)``). ``n_chains > 1`` takes S (C, K, K) and
+    b (K, C) and returns the (C, K) chain-major draws: C vmapped
+    Cholesky factorizations of lam*I + S_c. ``prior_precision`` is the
+    exact kernel SVM's lam*K prior (``posterior_params``)."""
+    with jax.named_scope("mstep"):
+        if n_chains > 1:
+            L, mu = jax.vmap(
+                lambda Sc, bc: posterior_params(Sc, bc, lam, jitter=jitter)
+            )(S, b.T)
+            return jax.vmap(draw_weight)(chain_keys(key, chain0, n_chains),
+                                         L, mu)
+        L, mu = posterior_params(S, b, lam, prior_precision=prior_precision,
+                                 jitter=jitter)
+        if mode == "EM":
+            return mu
+        if rng != "host":
+            key = jax.random.fold_in(key, chain0)
+        return draw_weight(key, L, mu)
+
+
+def chain_mean(x: jnp.ndarray, n_chains: int) -> jnp.ndarray:
+    """A chain-summed scalar as its cross-chain mean (identity for one
+    chain). Each driver applies it in its own float order: the resident
+    step after the psum, the stream driver on each chunk before the
+    sum."""
+    return x / n_chains if n_chains > 1 else x
 
 
 class StatsWindow:

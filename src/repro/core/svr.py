@@ -13,10 +13,10 @@ max(0, |y - w^T x| - eps_ins):
 
 Iteration cost is the paper's "constant factor of 2" over CLS (Sec 4.3).
 
-``svr_local_stats`` is the chunk-callable statistic (exact row sums),
-shared by the in-memory step, the mesh SPMD step, and the streaming
-driver's per-chunk accumulation — same pattern as
-``linear.accumulate_stats``.
+``svr_local_stats`` is the fused statistic over a row block and
+``svr_chunk_stats`` the E-step built on it (exact row sums), run by the
+in-memory step, the mesh SPMD step and the streaming driver's per-chunk
+accumulation alike — same pattern as ``linear.cls_chunk_stats``.
 """
 from __future__ import annotations
 
@@ -27,8 +27,8 @@ import jax
 import jax.numpy as jnp
 
 from repro.kernels import ops
-from . import augment, objective, stats
-from .linear import PhiSpec, SVMData, _k_block, chain_keys, multichain_draw
+from . import augment, objective
+from .linear import PhiSpec, SVMData, resident_step
 
 
 def svr_local_stats(X: jnp.ndarray, y: jnp.ndarray, w: jnp.ndarray, *,
@@ -106,28 +106,22 @@ def svr_chunk_stats(chunk: SVMData, w: jnp.ndarray, key: jax.Array,
                     eps_ins: float, backend: str | None, phi=None,
                     phi_spec: PhiSpec | None = None,
                     rng: str = "host", n_chains: int = 1,
-                    chain0: int = 0) -> dict:
-    """Streaming E-step body for SVR: one chunk's additive contributions
-    (tree-summed across chunks by the stream driver). Multichain chunks
-    carry S (C, K, K) / b (K, C) and chain-mean scalar diagnostics —
-    see ``linear.cls_chunk_stats``."""
+                    chain0: int = 0,
+                    col_window: tuple | None = None) -> dict:
+    """THE SVR E-step over one row block: its additive contributions,
+    tree-summed across chunks by the stream driver and run over the
+    whole shard by ``svr_step``. Multichain blocks carry
+    S (C, K, K) / b (K, C) and chain-summed scalars — see
+    ``linear.cls_chunk_stats``."""
     X, y, mask = chunk
     multi = n_chains > 1
     pred, gamma, omega, S, b = svr_local_stats(
         X, y, w.T if multi else w, mode=mode, key=key, eps=eps,
         eps_ins=eps_ins, backend=backend, row0=row0, phi=phi,
-        phi_spec=phi_spec, mask=mask, rng=rng, chain0=chain0)
+        phi_spec=phi_spec, mask=mask, col_window=col_window, rng=rng,
+        chain0=chain0)
     if multi:
-        maskc = jnp.broadcast_to(mask[:, None], pred.shape)
-        return {
-            "S": S,
-            "b": b,
-            "loss": objective.svr_obj_terms(pred, y[:, None], eps_ins,
-                                            maskc) / n_chains,
-            "gamma_sum": jnp.sum(gamma * maskc) / n_chains,
-            "omega_sum": jnp.sum(omega * maskc) / n_chains,
-            "mask_sum": jnp.sum(mask),
-        }
+        y, mask = y[:, None], jnp.broadcast_to(mask[:, None], pred.shape)
     return {
         "S": S,
         "b": b,
@@ -152,49 +146,14 @@ def svr_step(data: SVMData, w: jnp.ndarray, key: jax.Array, *,
              phi=None, phi_spec: PhiSpec | None = None,
              live: jnp.ndarray | None = None,
              rng: str = "host", n_chains: int = 1, chain0: int = 0):
-    """One LIN-*-SVR iteration. Returns (w_new, aux dict). ``live``
+    """One LIN-*-SVR iteration. Returns (w_new, aux dict) with aux keys
+    ``objective``, ``gamma_mean`` and ``omega_mean``. ``live``
     renormalizes the reductions around dropped replicas (stats.preduce).
     ``rng``/``n_chains``/``chain0`` mirror ``linear.cls_step``: the
     weight state is chain-major (C, K) when n_chains > 1."""
-    X, y, mask = data
-    multi = n_chains > 1
-    row0 = stats.shard_row_offset(X.shape[0], axes)
-
-    col_window = (_k_block(w.shape[0], k_shard_axis)
-                  if k_shard_axis is not None else None)
-    pred, gamma, omega, S, b = svr_local_stats(
-        X, y, w.T if multi else w, mode=mode, key=key, eps=eps,
-        eps_ins=eps_ins, backend=backend, row0=row0, phi=phi,
-        phi_spec=phi_spec, mask=mask, col_window=col_window, rng=rng,
-        chain0=chain0)
-    if k_shard_axis is None:
-        S, b = stats.reduce_stats(S, b, axes, triangle=triangle,
-                                  reduce_dtype=reduce_dtype, live=live)
-    else:
-        S, b = stats.reduce_kshard(S, b, axes, k_shard_axis,
-                                   reduce_dtype=reduce_dtype, live=live)
-
-    if multi:
-        w_new = multichain_draw(key, S, b, lam, jitter, chain0)
-        maskc = jnp.broadcast_to(mask[:, None], pred.shape)
-        obj = objective.l2_reg(w_new, lam) / n_chains + stats.preduce(
-            objective.svr_obj_terms(pred, y[:, None], eps_ins, maskc),
-            axes, live) / n_chains
-        return w_new, {
-            "objective": obj,
-            "gamma_mean": stats.masked_mean(gamma, maskc, axes, live),
-            "omega_mean": stats.masked_mean(omega, maskc, axes, live)}
-
-    L, mu = stats.posterior_params(S, b, lam, jitter=jitter)
-    if mode == "EM":
-        w_new = mu
-    elif rng == "host":
-        w_new = stats.draw_weight(key, L, mu)
-    else:
-        w_new = stats.draw_weight(chain_keys(key, chain0, 1)[0], L, mu)
-
-    obj = objective.l2_reg(w_new, lam) + stats.preduce(
-        objective.svr_obj_terms(pred, y, eps_ins, mask), axes, live)
-    return w_new, {"objective": obj,
-                   "gamma_mean": stats.masked_mean(gamma, mask, axes, live),
-                   "omega_mean": stats.masked_mean(omega, mask, axes, live)}
+    return resident_step(
+        svr_chunk_stats, data, w, key, mode=mode, lam=lam, jitter=jitter,
+        axes=axes, triangle=triangle, k_shard_axis=k_shard_axis,
+        reduce_dtype=reduce_dtype, live=live, rng=rng, n_chains=n_chains,
+        chain0=chain0, eps=eps, eps_ins=eps_ins, backend=backend, phi=phi,
+        phi_spec=phi_spec)
